@@ -55,8 +55,8 @@ def table(mesh: str = "pod"):
                 t_dom = max(rec.get("t_compute_s", 0),
                             rec.get("t_memory_s", 0),
                             rec.get("t_collective_s", 0))
-                from repro.launch.analysis import PEAK_FLOPS_BF16
-                t_useful = mf / nd / PEAK_FLOPS_BF16
+                from repro.launch.analysis import TARGET_KIND, peaks
+                t_useful = mf / nd / peaks(TARGET_KIND)["flops_bf16"]
                 row["roofline_fraction"] = round(t_useful / t_dom, 4) if t_dom else None
         else:
             row["note"] = rec.get("error", "")[:160]

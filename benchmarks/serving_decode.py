@@ -591,14 +591,16 @@ def bench_chaos(smoke: bool = False, posit: str = "p16") -> dict:
 
 
 # --------------------------------------------------------------------------
-# sharded serving: tok/s vs device count (each count in its own subprocess —
-# jax locks the host device count at first backend init)
+# sharded serving: tok/s vs device count (on the CPU each count runs in its
+# own subprocess — jax locks the host device count at first backend init;
+# on a TPU every count runs in this process, over a prefix of the chips)
 # --------------------------------------------------------------------------
 def _sharded_worker(devices: int, smoke: bool, posit: str) -> dict:
-    """Runs inside a subprocess whose XLA_FLAGS already forced `devices`
-    CPU host devices: one paged-engine drain on a (devices, 1) data-
-    parallel mesh (TP over CPU psums is pure overhead; the DP axis is the
-    throughput story), warmup pass excluded."""
+    """One paged-engine drain on a (devices, 1) data-parallel mesh over
+    the first `devices` devices (TP over CPU psums is pure overhead; the
+    DP axis is the throughput story), warmup pass excluded.  On the CPU it
+    runs inside a subprocess whose XLA_FLAGS forced `devices` host
+    devices."""
     from repro.launch.mesh import make_serving_mesh
     params, cfg = _bench_model(posit=posit)
     if smoke:
@@ -637,8 +639,19 @@ def bench_sharded(smoke: bool = False, posit: str = "p16",
     nightly artifact BENCH_serving_sharded.json).  On CPU the DP shards
     share physical cores, so this tracks scheduler/collective overhead
     rather than real speedup — the trend of interest is tok/s *not
-    collapsing* as the mesh widens."""
+    collapsing* as the mesh widens.
+
+    On a TPU a chip belongs to one process, and this one holds them all
+    once it asks JAX for its backend: the counts run here, one prefix mesh
+    each, and counts beyond the chips present are skipped."""
     import subprocess
+    import jax
+    if jax.default_backend() == "tpu":
+        rows = [_sharded_worker(n, smoke, posit) for n in device_counts
+                if n <= jax.device_count()]
+        return _write_sharded({"smoke": smoke, "posit": posit,
+                               "device_kind": jax.devices()[0].device_kind,
+                               "rows": rows})
     rows = []
     for n in device_counts:
         env = dict(os.environ,
@@ -656,7 +669,10 @@ def bench_sharded(smoke: bool = False, posit: str = "p16",
             raise RuntimeError(f"sharded worker ({n} devices) failed:\n"
                                f"{out.stderr[-2000:]}")
         rows.append(json.loads(out.stdout.strip().splitlines()[-1]))
-    res = {"smoke": smoke, "posit": posit, "rows": rows}
+    return _write_sharded({"smoke": smoke, "posit": posit, "rows": rows})
+
+
+def _write_sharded(res: dict) -> dict:
     os.makedirs(os.path.dirname(SHARDED_RESULTS_PATH), exist_ok=True)
     with open(SHARDED_RESULTS_PATH, "w") as f:
         json.dump(res, f, indent=1)

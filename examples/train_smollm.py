@@ -17,13 +17,10 @@ import argparse
 import json
 import os
 
-os.environ.setdefault("REPRO_USE_PALLAS", "1")
-if not os.environ.get("JAX_PLATFORMS", "").startswith("tpu"):
-    os.environ.setdefault("REPRO_PALLAS_INTERPRET", "1")
-
 import jax
 
 from repro.core.types import P16_2
+from repro.kernels import ops as kops
 from repro.data.pipeline import DataConfig
 from repro.models.transformer import ModelConfig, init_params
 from repro.optim.adamw import OptConfig
@@ -58,6 +55,12 @@ def main():
     ap.add_argument("--log-every", type=int, default=5)
     args = ap.parse_args()
 
+    # the kernel path everywhere; off a TPU (decided by the backend JAX
+    # initialized, not by JAX_PLATFORMS) the kernels run interpreted
+    os.environ.setdefault("REPRO_USE_PALLAS", "1")
+    if jax.default_backend() != "tpu":
+        os.environ.setdefault("REPRO_PALLAS_INTERPRET", "1")
+
     legs = {}
     for name, posit in (("p16", True), ("f32", False)):
         print(f"[example] === {name} leg "
@@ -86,7 +89,7 @@ def main():
                       "(flash fwd/bwd + posit GEMM custom_vjp + donated "
                       "train step)",
         "backend": jax.default_backend(),
-        "interpret": bool(os.environ.get("REPRO_PALLAS_INTERPRET")),
+        "interpret": kops.pallas_interpret(),
         "steps": args.steps,
         "seq_len": 64, "global_batch": 8,
         "p16": legs["p16"], "f32": legs["f32"],

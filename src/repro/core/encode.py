@@ -65,7 +65,10 @@ def encode_fir(s, te, M, W: int, sticky, cfg: PositConfig) -> jnp.ndarray:
     low_re = (combined_re & ((jnp.int32(1) << shiftB1) - 1)) != 0
     low_fr_all = frac != 0
     low_fr_tail = (frac & ((jnp.int32(1) << (W - 1)) - 1)) != 0
-    sB = jnp.where(shiftB > 0, low_re | low_fr_all, low_fr_tail).astype(jnp.int32) | sticky
+    # select between int32 flags, never between bools: Mosaic lowers a
+    # boolean select through an i8 -> i1 truncation it cannot compile
+    sB = jnp.where(shiftB > 0, (low_re | low_fr_all).astype(jnp.int32),
+                   low_fr_tail.astype(jnp.int32)) | sticky
 
     caseA = nre < body_bits
     body = jnp.where(caseA, bodyA, bodyB)

@@ -132,7 +132,7 @@ def block_psum(x):
     Megatron's g-operator: psum forward, *identity* backward.
 
     The identity backward is load-bearing for training: under shard_map
-    with check_rep=False, autodiff transposes a raw lax.psum to another
+    with check_vma=False, autodiff transposes a raw lax.psum to another
     psum, so a replicated cotangent flowing into the block output would
     multiply by the axis size at every block.  The block's output cotangent
     is already replicated (everything downstream of the psum is replicated
@@ -220,11 +220,10 @@ def cross_pod_grad_sync(grads, cfg: PositConfig | None, mesh,
 
     grads must already be laid out per `in_specs`; runs one shard_map.
     """
-    from jax.experimental.shard_map import shard_map
 
     def sync(g):
         g = jax.lax.psum(g, data_axis)                  # fast in-pod links, f32
         return compressed_grad_sync(g, pod_axis, cfg)   # slow links, posit wire
 
-    return shard_map(sync, mesh=mesh, in_specs=in_specs,
-                     out_specs=in_specs, check_rep=False)(grads)
+    return jax.shard_map(sync, mesh=mesh, in_specs=in_specs,
+                         out_specs=in_specs, check_vma=False)(grads)

@@ -39,6 +39,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.decode import decode_to_f32
 from repro.core.types import PositConfig
+from repro.kernels.tpu_params import compiler_params
 
 _NEG = -1e30
 
@@ -250,6 +251,11 @@ def _prefill_scratch(n_kv, groups, bq, d):
 # accumulators and must run in order
 _PREFILL_SEMANTICS = ("parallel", "parallel", "arbitrary")
 
+# the backward holds q, dO, K, V and the row-stat columns (lane-padded to
+# 128) double-buffered, plus its f32 accumulators: ~16.3 MiB at bq=128,
+# bk=256, H=15, d=64 (smollm-360m), just past Mosaic's 16 MiB default scope
+_BWD_VMEM_LIMIT = 32 * 2**20
+
 
 @functools.partial(
     jax.jit,
@@ -317,8 +323,7 @@ def paged_flash_prefill(q: jnp.ndarray, k_pages: jnp.ndarray,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, Sq + pq, d), jnp.float32),
-        compiler_params=pltpu.TPUCompilerParams(
-            dimension_semantics=_PREFILL_SEMANTICS),
+        compiler_params=compiler_params(*_PREFILL_SEMANTICS),
         interpret=interpret,
     )(page_table, seq_lens, q_offset, q, k_pages, v_pages)
     return out[:, :, :Sq, :]
@@ -405,8 +410,7 @@ def flash_prefill_contiguous(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         body,
         grid_spec=grid_spec,
         out_shape=out_shape,
-        compiler_params=pltpu.TPUCompilerParams(
-            dimension_semantics=_PREFILL_SEMANTICS),
+        compiler_params=compiler_params(*_PREFILL_SEMANTICS),
         interpret=interpret,
     )(kv_len, q_offset, q, k, v)
     if return_lse:
@@ -585,6 +589,10 @@ def flash_prefill_bwd_contiguous(q: jnp.ndarray, k: jnp.ndarray,
         do = jnp.pad(do, ((0, 0), (0, 0), (0, pq), (0, 0)))
         lse = jnp.pad(lse, ((0, 0), (0, 0), (0, pq)))
         delta = jnp.pad(delta, ((0, 0), (0, 0), (0, pq)))
+    # the kernels need the row stats as columns, (n_kv, groups*bq, 1): hand
+    # them over as [B, H, S, 1] so the in-kernel reshape only merges leading
+    # axes (Mosaic cannot relayout a lane-major (H, bq) tile into a column)
+    lse, delta = lse[..., None], delta[..., None]
     if pk:
         k = jnp.pad(k, ((0, 0), (0, 0), (0, pk), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, 0), (0, pk), (0, 0)))
@@ -593,7 +601,8 @@ def flash_prefill_bwd_contiguous(q: jnp.ndarray, k: jnp.ndarray,
     qd_spec = pl.BlockSpec((1, H, bq_, d), lambda b, i, j, sl, qo: (b, 0, i, 0))
     kv_spec = pl.BlockSpec((1, n_kv, bk_, d),
                            lambda b, i, j, sl, qo: (b, 0, j, 0))
-    row_spec = pl.BlockSpec((1, H, bq_), lambda b, i, j, sl, qo: (b, 0, i))
+    row_spec = pl.BlockSpec((1, H, bq_, 1),
+                            lambda b, i, j, sl, qo: (b, 0, i, 0))
 
     dq = pl.pallas_call(
         functools.partial(
@@ -608,8 +617,8 @@ def flash_prefill_bwd_contiguous(q: jnp.ndarray, k: jnp.ndarray,
             scratch_shapes=[pltpu.VMEM((n_kv, groups * bq_, d), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((B, H, Sq + pq, d), jnp.float32),
-        compiler_params=pltpu.TPUCompilerParams(
-            dimension_semantics=_PREFILL_SEMANTICS),
+        compiler_params=compiler_params(
+            *_PREFILL_SEMANTICS, vmem_limit_bytes=_BWD_VMEM_LIMIT),
         interpret=interpret,
     )(kv_len, q_offset, q, k, v, do, lse, delta)[:, :, :Sq, :]
 
@@ -622,7 +631,8 @@ def flash_prefill_bwd_contiguous(q: jnp.ndarray, k: jnp.ndarray,
                             lambda b, j, i, sl, qo: (b, 0, i, 0))
     kv_spec2 = pl.BlockSpec((1, n_kv, bk_, d),
                             lambda b, j, i, sl, qo: (b, 0, j, 0))
-    row_spec2 = pl.BlockSpec((1, H, bq_), lambda b, j, i, sl, qo: (b, 0, i))
+    row_spec2 = pl.BlockSpec((1, H, bq_, 1),
+                             lambda b, j, i, sl, qo: (b, 0, i, 0))
     dk, dv = pl.pallas_call(
         functools.partial(
             _prefill_bwd_dkv_body, n_kv=n_kv, groups=groups, bq=bq_, bk=bk_,
@@ -639,8 +649,8 @@ def flash_prefill_bwd_contiguous(q: jnp.ndarray, k: jnp.ndarray,
         ),
         out_shape=[jax.ShapeDtypeStruct((B, n_kv, Skv + pk, d), jnp.float32),
                    jax.ShapeDtypeStruct((B, n_kv, Skv + pk, d), jnp.float32)],
-        compiler_params=pltpu.TPUCompilerParams(
-            dimension_semantics=_PREFILL_SEMANTICS),
+        compiler_params=compiler_params(
+            *_PREFILL_SEMANTICS, vmem_limit_bytes=_BWD_VMEM_LIMIT),
         interpret=interpret,
     )(kv_len, q_offset, q, k, v, do, lse, delta)
     return dq, dk[:, :, :Skv, :], dv[:, :, :Skv, :]
@@ -697,8 +707,7 @@ def paged_flash_decode(q: jnp.ndarray, k_pages: jnp.ndarray,
                           window=window),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((bh, H, d), jnp.float32),
-        compiler_params=pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=compiler_params("parallel", "arbitrary"),
         interpret=interpret,
     )(page_table, seq_lens, q, k_pages, v_pages)
 
@@ -752,8 +761,7 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
             pltpu.VMEM((bq_, 128), jnp.float32),
             pltpu.VMEM((bq_, d), jnp.float32),
         ],
-        compiler_params=pltpu.TPUCompilerParams(
-            dimension_semantics=_PREFILL_SEMANTICS),
+        compiler_params=compiler_params(*_PREFILL_SEMANTICS),
         interpret=interpret,
     )(q, k, v)
     return out[:, :sq, :]

@@ -46,6 +46,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.decode import decode_to_f32
 from repro.core.types import PositConfig
+from repro.kernels.tpu_params import compiler_params
 
 
 def _round_up(x: int, m: int) -> int:
@@ -214,8 +215,7 @@ def posit_grouped_gemm(x: jnp.ndarray, w: jnp.ndarray,
                           transpose_b=transpose_b),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((Sp, Np), jnp.float32),
-        compiler_params=pltpu.TPUCompilerParams(
-            dimension_semantics=_GROUPED_SEMANTICS),
+        compiler_params=compiler_params(*_GROUPED_SEMANTICS),
         interpret=interpret,
     )(group_offsets.astype(jnp.int32), mt, gid, valid, x, w)[:S, :Nout]
     # tiles that no group touches are never written (their buffer content
@@ -315,8 +315,7 @@ def posit_grouped_gemm_dw(x: jnp.ndarray, g: jnp.ndarray,
         functools.partial(_grouped_dw_kernel, bm=bm_, L=L),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((E, Kp, Np), jnp.float32),
-        compiler_params=pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=compiler_params("parallel", "parallel", "arbitrary"),
         interpret=interpret,
     )(group_offsets.astype(jnp.int32), mt, gid, valid, x, g)[:, :K, :N]
     # empty groups own no incidence: their blocks were never written

@@ -66,6 +66,14 @@ def force_reference() -> bool:
     return env is not None and env not in ("0", "false", "False")
 
 
+def dispatch_key() -> tuple:
+    """The trace-time dispatch state: (use_pallas, force_reference,
+    pallas_interpret).  Jitted step builders that are cached across calls
+    key on it, so flipping the Pallas path off (a reference leg) retraces
+    instead of reusing a step traced with the kernels."""
+    return use_pallas(), force_reference(), pallas_interpret()
+
+
 # Pin the jnp-reference *backwards* while the forwards keep their kernels
 # (REPRO_FORCE_BWD_REFERENCE=1 or ops.FORCE_BWD_REFERENCE): the baseline
 # leg of benchmarks/train_step.py, and the oracle leg of the grad-parity

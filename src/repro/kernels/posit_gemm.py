@@ -29,6 +29,7 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core.convert import f32_to_posit
 from repro.core.decode import decode_to_f32
 from repro.core.types import PositConfig
+from repro.kernels.tpu_params import compiler_params
 
 
 def _pad_to(x: jnp.ndarray, m0: int, m1: int, value=0) -> jnp.ndarray:
@@ -77,6 +78,11 @@ def _gemm_kernel(a_ref, b_ref, o_ref, acc_ref, *, cfg_a, cfg_b, nk, out_posit,
 # i/j tiles own disjoint output blocks; only the k axis carries the
 # accumulator and must stay ordered
 _GEMM_SEMANTICS = ("parallel", "parallel", "arbitrary")
+
+# decoding *both* 512x512 operand tiles in VMEM (posit x posit) keeps ~19 MiB
+# of int32/f32 temporaries live, past Mosaic's 16 MiB default scope; v5e has
+# 128 MiB of VMEM per core
+_GEMM_VMEM_LIMIT = 32 * 2**20
 
 
 @functools.partial(
@@ -148,8 +154,8 @@ def posit_gemm(a: jnp.ndarray, b: jnp.ndarray, *,
         out_specs=pl.BlockSpec((bm_, bn_), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm_, bn_), jnp.float32)],
-        compiler_params=pltpu.TPUCompilerParams(
-            dimension_semantics=_GEMM_SEMANTICS),
+        compiler_params=compiler_params(*_GEMM_SEMANTICS,
+                                        vmem_limit_bytes=_GEMM_VMEM_LIMIT),
         interpret=interpret,
     )(a, b)
     return out[:m, :n]

@@ -10,18 +10,32 @@ and sum buffer sizes of all-gather / all-reduce / reduce-scatter /
 all-to-all / collective-permute ops (shapes there are per-device), with
 ring-algorithm byte factors.
 
-Hardware constants: TPU v5e targets (the container is CPU; these terms are
-*structural*, derived from the compiled module, not wall-clock).
+Hardware constants come from PEAKS, keyed by `device_kind` as JAX reports
+it; a kind missing from the table is an error, not a default.  The dry-run
+compiles for emulated devices, so it names its target kind (TARGET_KIND);
+these terms are *structural*, derived from the compiled module, not
+wall-clock.
 """
 from __future__ import annotations
 
 import dataclasses
 import re
 
-# --- TPU v5e hardware constants (per chip) ---
-PEAK_FLOPS_BF16 = 197e12     # FLOP/s
-HBM_BW = 819e9               # B/s
-ICI_BW = 50e9                # B/s per link (~bidirectional per-direction)
+# Per-chip peaks.  TPU v5e: Google Cloud documentation, "TPU v5e" (197
+# TFLOP/s bf16, 819 GB/s HBM, 1,600 Gbit/s of chip-to-chip interconnect
+# over 4 links = 50 GB/s per link and direction).
+PEAKS = {
+    "TPU v5 lite": {"flops_bf16": 197e12, "hbm_bw": 819e9, "ici_bw": 50e9},
+}
+TARGET_KIND = "TPU v5 lite"     # the production mesh (launch/mesh.py)
+
+
+def peaks(device_kind: str) -> dict:
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no peak rates for device kind {device_kind!r}; "
+                       f"known: {sorted(PEAKS)}") from None
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3": 1, "f8e5m2": 1,
@@ -86,8 +100,9 @@ def parse_collectives(hlo_text: str) -> CollectiveStats:
     return CollectiveStats(by_op=by_op, total_bytes=total)
 
 
-def roofline_terms(compiled, n_devices: int) -> dict:
-    """Three roofline terms (seconds) + raw counters from a compiled exe."""
+def roofline_terms(compiled, n_devices: int, pk: dict) -> dict:
+    """Three roofline terms (seconds) + raw counters from a compiled exe,
+    against the per-chip peaks `pk` (an entry of PEAKS)."""
     ca = compiled.cost_analysis()
     if isinstance(ca, list):
         ca = ca[0]
@@ -102,9 +117,9 @@ def roofline_terms(compiled, n_devices: int) -> dict:
         "bytes_per_device": byts,
         "collective_bytes_per_device": coll.total_bytes,
         "collectives_by_op": coll.by_op,
-        "t_compute_s": flops / PEAK_FLOPS_BF16,
-        "t_memory_s": byts / HBM_BW,
-        "t_collective_s": coll.total_bytes / ICI_BW,
+        "t_compute_s": flops / pk["flops_bf16"],
+        "t_memory_s": byts / pk["hbm_bw"],
+        "t_collective_s": coll.total_bytes / pk["ici_bw"],
         "n_devices": n_devices,
     }
     terms["bottleneck"] = max(

@@ -217,10 +217,11 @@ def probe_roofline(arch, shape, mesh, multi_pod, fmt) -> dict:
         rem_cost = (c3[i] - c1[i]) if c3 is not None else 0.0
         out[name] = max(outside, 0.0) + reps * per_pattern + rem_cost
     out["probe_collectives_by_op_2p"] = c2[3]
-    out["t_compute_s"] = out["flops_per_device"] / analysis.PEAK_FLOPS_BF16
-    out["t_memory_s"] = out["bytes_per_device"] / analysis.HBM_BW
+    pk = analysis.peaks(analysis.TARGET_KIND)
+    out["t_compute_s"] = out["flops_per_device"] / pk["flops_bf16"]
+    out["t_memory_s"] = out["bytes_per_device"] / pk["hbm_bw"]
     out["t_collective_s"] = (out["collective_bytes_per_device"]
-                             / analysis.ICI_BW)
+                             / pk["ici_bw"])
     out["bottleneck"] = max(
         ("compute", out["t_compute_s"]), ("memory", out["t_memory_s"]),
         ("collective", out["t_collective_s"]), key=lambda kv: kv[1])[0]
@@ -252,7 +253,8 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
                 t_lower = time.time() - t0
                 compiled = lowered.compile()
                 t_compile = time.time() - t0 - t_lower
-                terms = analysis.roofline_terms(compiled, mesh.size)
+                terms = analysis.roofline_terms(
+                    compiled, mesh.size, analysis.peaks(analysis.TARGET_KIND))
                 print(compiled.memory_analysis())
                 # scan bodies are cost-counted once; probes fix trip counts
                 terms.update(probe_roofline(arch, shape, mesh, multi_pod,

@@ -81,11 +81,13 @@ def main():
     from repro import configs
     from repro.checkpoint import store
     from repro.core.types import P8_2, P16_2
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models.transformer import init_params
     from repro.quant.policy import PositPolicy
     from repro.quant.ptq import quantize_for_serving
     from repro.serving.engine import PagedServingEngine, generate
 
+    enable_compile_cache()
     pcfg = {"p8": P8_2, "p16": P16_2}.get(args.posit)
     policy = PositPolicy(weights=pcfg, kv_cache=pcfg) if pcfg else PositPolicy()
     get = configs.get_smoke if args.smoke else configs.get_config

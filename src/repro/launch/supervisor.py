@@ -35,6 +35,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import glob
 import os
 import signal
 import socket
@@ -227,6 +228,18 @@ def _monitor(procs, hb_paths, policy: RestartPolicy, poll_s: float,
 # the training worker group
 # --------------------------------------------------------------------------
 
+def tpu_chips_on_host() -> int:
+    """TPU chips this host exposes, counted from their device nodes
+    (/dev/accel<n>, or /dev/vfio/<n> on generations that pass chips through
+    VFIO); 0 when JAX_PLATFORMS pins the CPU.  No JAX here: on a TPU host a
+    process that initializes JAX holds every chip, so the parent never
+    does."""
+    if os.environ.get("JAX_PLATFORMS", "").split(",")[0] == "cpu":
+        return 0
+    return (len(glob.glob("/dev/accel[0-9]*"))
+            or len(glob.glob("/dev/vfio/[0-9]*")))
+
+
 def _worker_env():
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)   # forced host-device counts break gloo init
@@ -251,6 +264,16 @@ def supervise_training(arch: str, steps: int, ckpt_dir: str, run_dir: str, *,
     only — restarted generations run clean, which is what lets the tests
     assert recovery."""
     policy = policy or RestartPolicy()
+    chips = tpu_chips_on_host()
+    if chips and workers > 1:
+        # every worker would initialize the TPU backend and claim all the
+        # chips: the second one fails on libtpu's lock or hangs.  The
+        # group's gradient exchange runs over gloo on the host, so there is
+        # no per-chip layout to hand out yet.
+        raise ValueError(
+            f"--workers {workers} on a TPU host ({chips} chip(s)): a worker "
+            f"process holds every chip it can see, so the elastic group "
+            f"runs one worker per TPU host; use --workers 1")
     env = _worker_env()
 
     def make_cmd(gen, host_id, num_hosts, port, hb_path):
@@ -313,8 +336,11 @@ def _worker_main(args):
 
     from repro.data.pipeline import DataConfig
     from repro.distributed.fault_tolerance import Heartbeat
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.optim.adamw import OptConfig
     from repro.training.elastic import elastic_train_loop
+
+    enable_compile_cache()
 
     cfg = _resolve_cfg(args.arch, args.smoke, args.posit)
     opt_cfg = OptConfig(lr_peak=args.lr,

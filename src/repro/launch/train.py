@@ -21,11 +21,15 @@ in-process fault_tolerance.StepWatchdog on the plain single-process path;
 --async-ckpt moves checkpoint writes off the training thread
 (checkpoint/async_store.py).
 
-XLA flags for real hardware (latency-hiding overlap of the FSDP gathers —
-DESIGN.md §5) are exported here so runs inherit them:
+TPU compiler flags (latency-hiding overlap of the FSDP gathers —
+DESIGN.md §5) are exported here so runs, and the supervisor's workers,
+inherit them:
     --xla_tpu_enable_async_collective_fusion=true
     --xla_tpu_enable_latency_hiding_scheduler=true
     --xla_tpu_overlap_compute_collective_tc=true
+They go through LIBTPU_INIT_ARGS, which libtpu parses and everything else
+ignores: libtpu 0.0.34 accepts all three there, while XLA_FLAGS aborts the
+process on them ("Unknown flag in XLA_FLAGS").
 """
 from __future__ import annotations
 
@@ -33,11 +37,35 @@ import argparse
 import os
 
 
-TPU_XLA_FLAGS = (
+TPU_LIBTPU_FLAGS = (
     "--xla_tpu_enable_async_collective_fusion=true "
     "--xla_tpu_enable_latency_hiding_scheduler=true "
     "--xla_tpu_overlap_compute_collective_tc=true"
 )
+
+
+def run_configs(arch: str, *, steps: int, seq_len: int, global_batch: int,
+                lr: float, posit: str, smoke: bool = False):
+    """(model, optimizer, data) configs of one training run: posit STE
+    weights per `posit` (off | p8 | p16), warmup over the first tenth of
+    the steps (at most 100).  main() and chip_smoke.py both build runs
+    here."""
+    from repro import configs
+    from repro.core.types import P8_2, P16_2
+    from repro.data.pipeline import DataConfig
+    from repro.optim.adamw import OptConfig
+    from repro.quant.policy import PositPolicy
+
+    policy = {"off": PositPolicy(),
+              "p8": PositPolicy(weights=P8_2),
+              "p16": PositPolicy(weights=P16_2)}[posit]
+    get = configs.get_smoke if smoke else configs.get_config
+    cfg = get(arch, policy=policy)
+    opt_cfg = OptConfig(lr_peak=lr, warmup_steps=min(100, steps // 10 + 1),
+                        total_steps=steps)
+    data_cfg = DataConfig(vocab=cfg.vocab, seq_len=seq_len,
+                          global_batch=global_batch)
+    return cfg, opt_cfg, data_cfg
 
 
 def main():
@@ -72,6 +100,11 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    # ahead of the inherited value: libtpu keeps the last of a repeated flag
+    os.environ["LIBTPU_INIT_ARGS"] = (
+        TPU_LIBTPU_FLAGS + " "
+        + os.environ.get("LIBTPU_INIT_ARGS", "")).strip()
+
     if args.workers > 1 or args.restart_on_failure:
         # elastic supervisor path: the trainer runs in child processes
         # that are re-execed (and the group shrunk) on failure
@@ -103,28 +136,15 @@ def main():
             os.environ.get("XLA_FLAGS", "") +
             f" --xla_force_host_platform_device_count={args.host_devices}")
 
-    if os.environ.get("JAX_PLATFORMS", "") not in ("", "cpu"):
-        os.environ["XLA_FLAGS"] = (TPU_XLA_FLAGS + " "
-                                   + os.environ.get("XLA_FLAGS", ""))
-
-    from repro import configs
-    from repro.core.types import P8_2, P16_2
-    from repro.data.pipeline import DataConfig
     from repro.distributed.fault_tolerance import RestartPolicy
-    from repro.optim.adamw import OptConfig
-    from repro.quant.policy import PositPolicy
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.training.trainer import train_loop
 
-    policy = {"off": PositPolicy(),
-              "p8": PositPolicy(weights=P8_2),
-              "p16": PositPolicy(weights=P16_2)}[args.posit]
-    get = configs.get_smoke if args.smoke else configs.get_config
-    cfg = get(args.arch, policy=policy)
-
-    opt_cfg = OptConfig(lr_peak=args.lr, warmup_steps=min(100, args.steps // 10 + 1),
-                        total_steps=args.steps)
-    data_cfg = DataConfig(vocab=cfg.vocab, seq_len=args.seq_len,
-                          global_batch=args.global_batch)
+    enable_compile_cache()
+    cfg, opt_cfg, data_cfg = run_configs(
+        args.arch, steps=args.steps, seq_len=args.seq_len,
+        global_batch=args.global_batch, lr=args.lr, posit=args.posit,
+        smoke=args.smoke)
     rp = RestartPolicy(ckpt_every=args.ckpt_every,
                        step_timeout_s=args.step_timeout)
 

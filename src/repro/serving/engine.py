@@ -97,7 +97,25 @@ def sample(logits, key, temperature: float = 0.0):
     return jax.random.categorical(key, logits / temperature, axis=-1)
 
 
-@functools.lru_cache(maxsize=64)
+def _step_cache(maxsize: int):
+    """lru_cache for the jitted step builders, keyed on the arguments *and*
+    on kernels.ops.dispatch_key().  Kernel dispatch is decided at trace
+    time, so a step built with the Pallas path switched off must not reuse
+    a trace made with it on (or the reverse); keying here means no caller
+    has to remember to pass the dispatch state."""
+    def deco(build):
+        cached = functools.lru_cache(maxsize=maxsize)(
+            lambda dispatch, *a, **kw: build(*a, **kw))
+
+        @functools.wraps(build)
+        def get(*a, **kw):
+            from repro.kernels.ops import dispatch_key
+            return cached(dispatch_key(), *a, **kw)
+        return get
+    return deco
+
+
+@_step_cache(maxsize=64)
 def _dense_steps(cfg: ModelConfig):
     """Jitted prefill/decode steps, built once per model config.
 
@@ -182,7 +200,8 @@ def _sample_on_device(last, *, greedy: bool, temperature, seed, step_idx,
 
 def _step_body(cfg: ModelConfig, greedy: bool, p, tokens, pages, pt, sl, nn,
                temp, seed, step_idx, poison, *, slot_offset=0,
-               tp_size: int = 1, vocab_sharded: bool = False, compress=None):
+               tp_size: int = 1, vocab_sharded: bool = False, compress=None,
+               with_logits: bool = False):
     """The paged serving step, shared verbatim by the single-device and the
     mesh-sharded builders (under shard_map the tensor_parallel context and
     the shard's slot_offset are the only differences — keeping one body
@@ -196,7 +215,11 @@ def _step_body(cfg: ModelConfig, greedy: bool, p, tokens, pages, pt, sl, nn,
     logit domain, eq. (4) pattern check landed after decode), so a poisoned
     KV page, a poisoned activation or a real numerical blowup all trip it,
     and only for the slot that produced it.  The flags ride back with the
-    sampled tokens; the happy path pays no extra host sync."""
+    sampled tokens; the happy path pays no extra host sync.
+
+    with_logits: also return the [B, vocab] last-position logits (vocab-
+    sharded under TP) as a fourth output — the engine's `record_logits`
+    mode, which compares logits against a reference path."""
     from repro.distributed.collectives import tensor_parallel
 
     with tensor_parallel("model", tp_size, vocab_sharded, compress):
@@ -218,11 +241,14 @@ def _step_body(cfg: ModelConfig, greedy: bool, p, tokens, pages, pt, sl, nn,
                              vocab_sharded=vocab_sharded)
     # a NaR'd row samples garbage (argmax over NaNs) — the host discards
     # the token for flagged slots and fails the request instead
+    if with_logits:
+        return toks, nar, extract_paged_pages(new_caches), last
     return toks, nar, extract_paged_pages(new_caches)
 
 
-@functools.lru_cache(maxsize=64)
-def _paged_step(cfg: ModelConfig, greedy: bool = True):
+@_step_cache(maxsize=64)
+def _paged_step(cfg: ModelConfig, greedy: bool = True,
+                with_logits: bool = False):
     """The fused paged serving step, jitted once per (model config, sampling
     mode) and shared by every engine instance (a per-engine jit would
     recompile identical shapes for each engine — e.g. one per benchmark
@@ -233,14 +259,14 @@ def _paged_step(cfg: ModelConfig, greedy: bool = True):
         STEP_TRACES[("paged_step", cfg.name, tokens.shape[1],
                      pt.shape[1])] += 1
         return _step_body(cfg, greedy, p, tokens, pages, pt, sl, nn, temp,
-                          seed, step_idx, poison)
+                          seed, step_idx, poison, with_logits=with_logits)
 
     return jax.jit(step, donate_argnums=(2,))
 
 
-@functools.lru_cache(maxsize=16)
+@_step_cache(maxsize=16)
 def _sharded_paged_step(cfg: ModelConfig, mesh, greedy: bool = True,
-                        compress=None):
+                        compress=None, with_logits: bool = False):
     """The mesh-sharded paged serving step: one shard_map over the
     ("data", "model") mesh, jitted once per (config, mesh, sampling mode).
 
@@ -255,9 +281,9 @@ def _sharded_paged_step(cfg: ModelConfig, mesh, greedy: bool = True,
 
     Sampling runs on device inside the shard_map (a host round-trip per
     token would serialize the mesh): the step returns only the [max_seqs]
-    int32 token ids, data-sharded like the slots.
+    int32 token ids, data-sharded like the slots.  `with_logits` as for
+    _step_body.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.distributed.sharding import (paged_pool_pspecs,
                                             serving_param_pspecs)
@@ -272,18 +298,21 @@ def _sharded_paged_step(cfg: ModelConfig, mesh, greedy: bool = True,
             cfg, greedy, p, tokens, pages, pt, sl, nn, temp, seed, step_idx,
             poison,
             slot_offset=jax.lax.axis_index("data") * tokens.shape[0],
-            tp_size=ntp, vocab_sharded=vocab_sharded, compress=compress)
+            tp_size=ntp, vocab_sharded=vocab_sharded, compress=compress,
+            with_logits=with_logits)
 
     def step(p, tokens, pages, pt, sl, nn, temp, seed, step_idx, poison):
         data_rows = P("data", None)
-        return shard_map(
+        out_specs = (P("data"), P("data"), paged_pool_pspecs(pages, mesh))
+        if with_logits:
+            out_specs += (P("data", "model" if vocab_sharded else None),)
+        return jax.shard_map(
             body, mesh=mesh,
             in_specs=(serving_param_pspecs(p, mesh), data_rows,
                       paged_pool_pspecs(pages, mesh), data_rows,
                       P("data"), P("data"), P(), P(), P(), P("data")),
-            out_specs=(P("data"), P("data"),
-                       paged_pool_pspecs(pages, mesh)),
-            check_rep=False,
+            out_specs=out_specs,
+            check_vma=False,
         )(p, tokens, pages, pt, sl, nn, temp, seed, step_idx, poison)
 
     return jax.jit(step, donate_argnums=(2,))
@@ -306,7 +335,6 @@ def _sharded_paged_copy(cfg: ModelConfig, mesh):
     (copy-on-write never crosses sub-pools — dedup is shard-local so DP
     stays bit-parity with the single-device engine).  Shards with nothing
     to copy get (0, 0): the garbage page copied onto itself, a no-op."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.distributed.sharding import paged_pool_pspecs
 
@@ -315,9 +343,10 @@ def _sharded_paged_copy(cfg: ModelConfig, mesh):
             return copy_paged_pages(pages, src[0], dst[0])
 
         specs = paged_pool_pspecs(pages, mesh)
-        return shard_map(body, mesh=mesh,
-                         in_specs=(specs, P("data"), P("data")),
-                         out_specs=specs, check_rep=False)(pages, src, dst)
+        return jax.shard_map(body, mesh=mesh,
+                             in_specs=(specs, P("data"), P("data")),
+                             out_specs=specs,
+                             check_vma=False)(pages, src, dst)
 
     return jax.jit(step, donate_argnums=(0,))
 
@@ -470,6 +499,11 @@ class PagedServingEngine:
         requires mesh=None (the injector targets shard-local page ids).
         None (default): no injection; the detection/containment paths stay
         active for real faults either way.
+    record_logits: keep the logits behind every token a request is given
+        (`token_logits[rid][k]`, [vocab] f32, for its k-th generated
+        token) — the step then also returns its last-position logits,
+        O(max_seqs * vocab) device->host per step.
+        For checking logits against a reference path; off in serving.
     """
 
     def __init__(self, params, cfg: ModelConfig, *, max_seqs: int = 8,
@@ -483,7 +517,7 @@ class PagedServingEngine:
                  max_waiting: int | None = None,
                  default_ttl_steps: int | None = None,
                  default_deadline_s: float | None = None,
-                 chaos=None):
+                 chaos=None, record_logits: bool = False):
         self.params, self.cfg = params, cfg
         self.max_seqs, self.page = max_seqs, page_size
         self.width = table_width
@@ -597,6 +631,8 @@ class PagedServingEngine:
         self._step_idx = 0
         self.finished: dict[int, np.ndarray] = {}
         self.outcomes: dict[int, RequestOutcome] = {}
+        self.record_logits = record_logits
+        self.token_logits: dict[int, list[np.ndarray]] = {}
         self.max_waiting = max_waiting
         self.default_ttl_steps = default_ttl_steps
         self.default_deadline_s = default_deadline_s
@@ -627,10 +663,10 @@ class PagedServingEngine:
 
         greedy = temperature <= 0.0
         if mesh is None:
-            self._step_fn = _paged_step(cfg, greedy)
+            self._step_fn = _paged_step(cfg, greedy, record_logits)
         else:
             self._step_fn = _sharded_paged_step(cfg, mesh, greedy,
-                                                tp_compress)
+                                                tp_compress, record_logits)
 
     # ---- host-side paging ------------------------------------------------
     def _shard(self, i: int) -> int:
@@ -1175,17 +1211,22 @@ class PagedServingEngine:
         """Run the fused step; returns (tokens, nar) — the sampled token
         and the on-device NaR-detector flag per slot ([max_seqs] int32 /
         bool, fetched in one transfer, so the happy path costs no extra
-        host sync).  A step that fails (InjectedFault before the device
-        call) is retried once against unchanged state; a repeat failure
-        quarantines the participants and returns (None, None)."""
+        host sync; under record_logits the step's last-position logits
+        land in self._last_logits).  A step that fails (InjectedFault
+        before the device call) is retried once against unchanged state; a
+        repeat failure quarantines the participants and returns
+        (None, None)."""
         t_step0 = time.perf_counter()
         poisoned: list[int] = []
         if self._chaos is not None:
             poisoned = self._chaos.poison_slots(self._step_idx, participants)
         poison = np.zeros((self.max_seqs,), bool)
         poison[poisoned] = True
-        pt = jnp.asarray(self._table_view(participants))
-        sl = jnp.asarray(self.seq_lens)
+        # snapshot the host tables: on the CPU backend jnp.asarray may alias
+        # a numpy buffer, and the host advances seq_lens and reclaims table
+        # entries while the dispatched step can still be reading them
+        pt = jnp.asarray(np.array(self._table_view(participants)))
+        sl = jnp.asarray(self.seq_lens.copy())
         nn = jnp.asarray(num_new)
         for attempt in (0, 1):
             try:
@@ -1199,7 +1240,7 @@ class PagedServingEngine:
                         raise InjectedFault(
                             f"injected device failure at step "
                             f"{self._step_idx} attempt {attempt}")
-                toks, bad, self.pages = self._step_fn(
+                toks, bad, self.pages, *last = self._step_fn(
                     self.params, jnp.asarray(tokens), self.pages, pt, sl, nn,
                     jnp.float32(self.temperature), jnp.int32(self._seed),
                     jnp.int32(self._step_idx), jnp.asarray(poison))
@@ -1214,11 +1255,20 @@ class PagedServingEngine:
         self._step_idx += 1
         self.seq_lens += num_new
         self._reclaim_expired()
-        toks, bad = jax.device_get((toks, bad))
+        toks, bad, last = jax.device_get((toks, bad, last))
+        if last:
+            self._last_logits = np.asarray(last[0])
         # end-to-end wall time of the fused step (injected straggler sleeps
         # included — that skew is exactly what the p99 is for)
         self._step_lat_s.append(time.perf_counter() - t_step0)
         return np.asarray(toks), np.asarray(bad)
+
+    def _keep_logits(self, i: int):
+        """record_logits: keep slot i's last-step logits, which produced the
+        token it is about to be given."""
+        if self.record_logits:
+            self.token_logits.setdefault(self.slots[i].req.rid, []).append(
+                self._last_logits[i])
 
     def _reclaim_expired(self):
         """Free KV pages every token of which has slid out of the attention
@@ -1310,6 +1360,7 @@ class PagedServingEngine:
                                     "NaR detected in output logits")
                     continue
                 if s.phase == "decode":
+                    self._keep_logits(i)
                     tok = int(toks[i])
                     s.generated.append(tok)
                     s.next_token = tok
@@ -1342,6 +1393,7 @@ class PagedServingEngine:
                 self._fail_slot(i, "failed_nar",
                                 "NaR detected in output logits")
                 continue
+            self._keep_logits(i)
             tok = int(toks[i])
             s.generated.append(tok)
             s.next_token = tok

@@ -207,7 +207,6 @@ def make_train_step(cfg: ModelConfig, opt_cfg: adamw.OptConfig, mesh=None, *,
                                   accum_steps)
         return jax.jit(step, donate_argnums=(0, 1) if donate else ())
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.distributed.collectives import (compressed_grad_sync,
                                                tensor_parallel)
@@ -260,21 +259,21 @@ def make_train_step(cfg: ModelConfig, opt_cfg: adamw.OptConfig, mesh=None, *,
         def step(params, opt_state, batch, poison):
             opt_state = _backfill(opt_state)
             pspecs, ospecs, bspecs = _specs(params, opt_state, batch)
-            return shard_map(
+            return jax.shard_map(
                 functools.partial(body, pspecs), mesh=mesh,
                 in_specs=(pspecs, ospecs, bspecs, P()),
                 out_specs=(pspecs, ospecs, P()),
-                check_rep=False,
+                check_vma=False,
             )(params, opt_state, batch, poison)
     else:
         def step(params, opt_state, batch):
             opt_state = _backfill(opt_state)
             pspecs, ospecs, bspecs = _specs(params, opt_state, batch)
-            return shard_map(
+            return jax.shard_map(
                 functools.partial(body, pspecs), mesh=mesh,
                 in_specs=(pspecs, ospecs, bspecs),
                 out_specs=(pspecs, ospecs, P()),
-                check_rep=False,
+                check_vma=False,
             )(params, opt_state, batch)
 
     return jax.jit(step, donate_argnums=(0, 1) if donate else ())

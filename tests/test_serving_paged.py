@@ -289,3 +289,51 @@ def test_paged_engine_zero_retrace_steady_state():
     eng3.run([(np.asarray(prompts[i]), 4) for i in range(4)])
     after = dict(E.STEP_TRACES)
     assert after == before, (before, after)
+
+
+def test_engine_record_logits_first_token_matches_dense_prefill():
+    """record_logits keeps the logits behind every generated token: the
+    first are the dense cached forward's last prompt position, each later
+    one the dense decode step fed the previous token, and the argmax of
+    each is the token the engine emitted."""
+    params, cfg, prompts = _engine_model()
+    max_new = 3
+    eng = E.PagedServingEngine(params, cfg, max_seqs=4, page_size=4,
+                               table_width=8, prefill_chunk=4,
+                               record_logits=True)
+    res = eng.run([(np.asarray(prompts[i]), max_new) for i in range(4)])
+    assert sorted(eng.token_logits) == list(range(4))
+    caches = init_caches(cfg, 4, 32)
+    want, _, caches = forward(params, cfg, tokens=prompts, caches=caches)
+    want = [want[:, -1]]
+    for k in range(1, max_new):
+        tok = jnp.asarray(np.stack([res[i][k - 1] for i in range(4)]))
+        nxt, _, caches = forward(params, cfg, tokens=tok[:, None],
+                                 caches=caches)
+        want.append(nxt[:, -1])
+    for i in range(4):
+        got = eng.token_logits[i]
+        assert len(got) == max_new
+        for k in range(max_new):
+            assert got[k].shape == (cfg.vocab,)
+            np.testing.assert_allclose(got[k], np.asarray(want[k][i]),
+                                       rtol=1e-5, atol=1e-5)
+            assert int(np.argmax(got[k])) == int(res[i][k])
+
+
+def test_paged_step_cache_keys_on_kernel_dispatch(monkeypatch):
+    """Kernel dispatch is decided at trace time, so an engine built with the
+    Pallas path switched on must not share the step traced without it —
+    nor may generate()'s dense steps."""
+    params, cfg, _ = _engine_model()
+    kw = dict(max_seqs=4, page_size=4, table_width=8, prefill_chunk=8)
+    monkeypatch.setenv("REPRO_USE_PALLAS", "0")
+    ref = E.PagedServingEngine(params, cfg, **kw)
+    ref_dense = E._dense_steps(cfg)
+    assert E.PagedServingEngine(params, cfg, **kw)._step_fn is ref._step_fn
+    assert E._dense_steps(cfg) is ref_dense
+    monkeypatch.setenv("REPRO_USE_PALLAS", "1")
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
+    assert E.PagedServingEngine(params, cfg, **kw)._step_fn \
+        is not ref._step_fn
+    assert E._dense_steps(cfg) is not ref_dense

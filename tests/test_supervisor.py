@@ -148,6 +148,36 @@ def test_supervisor_startup_timeout(tmp_path):
     assert out.final_workers == 1
 
 
+@pytest.mark.parametrize("chips,workers,refused",
+                         [(1, 2, True), (4, 4, True), (4, 1, False),
+                          (0, 4, False)])
+def test_training_group_on_tpu_host_one_worker(tmp_path, monkeypatch, chips,
+                                               workers, refused):
+    """On a TPU host every worker would claim all the chips, so a group of
+    more than one worker is refused up front instead of hanging; a single
+    worker (or a CPU host) reaches the spawn."""
+    from repro.launch import supervisor as sup
+    monkeypatch.setattr(sup, "tpu_chips_on_host", lambda: chips)
+    spawned = []
+    monkeypatch.setattr(sup, "supervise",
+                        lambda make_cmd, n, *a, **k: spawned.append(n))
+    if refused:
+        with pytest.raises(ValueError, match="one worker per TPU host"):
+            sup.supervise_training("tiny", 1, str(tmp_path), str(tmp_path),
+                                   workers=workers, verbose=False)
+        assert not spawned
+    else:
+        sup.supervise_training("tiny", 1, str(tmp_path), str(tmp_path),
+                               workers=workers, verbose=False)
+        assert spawned == [workers]
+
+
+def test_tpu_chips_on_host_zero_when_cpu_pinned(monkeypatch):
+    from repro.launch.supervisor import tpu_chips_on_host
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    assert tpu_chips_on_host() == 0
+
+
 # ---------------------------------------------------------------------------
 # full stack: real jax.distributed training groups (nightly elastic lane)
 # ---------------------------------------------------------------------------

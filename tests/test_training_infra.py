@@ -110,7 +110,6 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 from repro.core.types import P16_2
 from repro.distributed.collectives import compressed_psum
 
@@ -120,8 +119,8 @@ x = jnp.asarray(np.random.default_rng(0).normal(size=(8, 64)), jnp.float32)
 def f(xs):
     return compressed_psum(xs, "data", P16_2)
 
-got = shard_map(f, mesh=mesh, in_specs=P("data", None),
-                out_specs=P("data", None), check_rep=False)(x)
+got = jax.shard_map(f, mesh=mesh, in_specs=P("data", None),
+                    out_specs=P("data", None), check_vma=False)(x)
 want = x.sum(axis=0, keepdims=True).repeat(8, 0)
 rel = np.abs(np.asarray(got) - np.asarray(want)) / (np.abs(np.asarray(want)) + 1e-9)
 assert rel.max() < 2e-3, rel.max()   # p16: ~2^-13 relative rounding + margin
@@ -133,3 +132,28 @@ print("OK", rel.max())
                          capture_output=True, text=True, timeout=300)
     assert out.returncode == 0, out.stderr[-2000:]
     assert "OK" in out.stdout
+
+
+def test_compile_cache_follows_env(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR wins untouched; without it the cache goes
+    to the fixed .jax_cache/ at the root of the checkout."""
+    from repro.launch import compile_cache as cc
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv(cc.CACHE_ENV, "/some/cache")
+        assert cc.enable_compile_cache() == "/some/cache"
+        assert jax.config.jax_compilation_cache_dir == prev
+        monkeypatch.delenv(cc.CACHE_ENV)
+        got = cc.enable_compile_cache()
+        assert got == os.path.join(cc.REPO_ROOT, ".jax_cache")
+        assert os.path.isfile(os.path.join(cc.REPO_ROOT, "chip_smoke.py"))
+        assert jax.config.jax_compilation_cache_dir == got
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
+
+
+def test_peaks_keyed_by_device_kind():
+    from repro.launch.analysis import TARGET_KIND, peaks
+    assert peaks(TARGET_KIND)["flops_bf16"] == 197e12
+    with pytest.raises(KeyError, match="no peak rates"):
+        peaks("cpu")

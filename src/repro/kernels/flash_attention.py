@@ -324,6 +324,7 @@ def paged_flash_prefill(q: jnp.ndarray, k_pages: jnp.ndarray,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, Sq + pq, d), jnp.float32),
         compiler_params=compiler_params(*_PREFILL_SEMANTICS),
+        name="paged_flash_prefill",
         interpret=interpret,
     )(page_table, seq_lens, q_offset, q, k_pages, v_pages)
     return out[:, :, :Sq, :]
@@ -411,6 +412,7 @@ def flash_prefill_contiguous(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         grid_spec=grid_spec,
         out_shape=out_shape,
         compiler_params=compiler_params(*_PREFILL_SEMANTICS),
+        name="flash_prefill_contiguous",
         interpret=interpret,
     )(kv_len, q_offset, q, k, v)
     if return_lse:
@@ -619,6 +621,7 @@ def flash_prefill_bwd_contiguous(q: jnp.ndarray, k: jnp.ndarray,
         out_shape=jax.ShapeDtypeStruct((B, H, Sq + pq, d), jnp.float32),
         compiler_params=compiler_params(
             *_PREFILL_SEMANTICS, vmem_limit_bytes=_BWD_VMEM_LIMIT),
+        name="flash_prefill_bwd_contiguous",
         interpret=interpret,
     )(kv_len, q_offset, q, k, v, do, lse, delta)[:, :, :Sq, :]
 
@@ -651,6 +654,7 @@ def flash_prefill_bwd_contiguous(q: jnp.ndarray, k: jnp.ndarray,
                    jax.ShapeDtypeStruct((B, n_kv, Skv + pk, d), jnp.float32)],
         compiler_params=compiler_params(
             *_PREFILL_SEMANTICS, vmem_limit_bytes=_BWD_VMEM_LIMIT),
+        name="flash_prefill_bwd_contiguous",
         interpret=interpret,
     )(kv_len, q_offset, q, k, v, do, lse, delta)
     return dq, dk[:, :, :Skv, :], dv[:, :, :Skv, :]
@@ -708,6 +712,7 @@ def paged_flash_decode(q: jnp.ndarray, k_pages: jnp.ndarray,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((bh, H, d), jnp.float32),
         compiler_params=compiler_params("parallel", "arbitrary"),
+        name="paged_flash_decode",
         interpret=interpret,
     )(page_table, seq_lens, q, k_pages, v_pages)
 
@@ -762,6 +767,7 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
             pltpu.VMEM((bq_, d), jnp.float32),
         ],
         compiler_params=compiler_params(*_PREFILL_SEMANTICS),
+        name="flash_attention",
         interpret=interpret,
     )(q, k, v)
     return out[:, :sq, :]

@@ -216,6 +216,7 @@ def posit_grouped_gemm(x: jnp.ndarray, w: jnp.ndarray,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((Sp, Np), jnp.float32),
         compiler_params=compiler_params(*_GROUPED_SEMANTICS),
+        name="posit_grouped_gemm",
         interpret=interpret,
     )(group_offsets.astype(jnp.int32), mt, gid, valid, x, w)[:S, :Nout]
     # tiles that no group touches are never written (their buffer content
@@ -316,6 +317,7 @@ def posit_grouped_gemm_dw(x: jnp.ndarray, g: jnp.ndarray,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((E, Kp, Np), jnp.float32),
         compiler_params=compiler_params("parallel", "parallel", "arbitrary"),
+        name="posit_grouped_gemm_dw",
         interpret=interpret,
     )(group_offsets.astype(jnp.int32), mt, gid, valid, x, g)[:, :K, :N]
     # empty groups own no incidence: their blocks were never written

@@ -50,6 +50,7 @@ def decode_block(p: jnp.ndarray, cfg: PositConfig, *, block_rows: int = 128,
         in_specs=[pl.BlockSpec((block_rows, _WIDTH), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((block_rows, _WIDTH), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(t.shape, jnp.float32),
+        name="decode_block",
         interpret=interpret,
     )(t)
     return out.reshape(-1)[:size].reshape(shape)
@@ -68,6 +69,7 @@ def encode_block(v: jnp.ndarray, cfg: PositConfig, *, block_rows: int = 128,
         in_specs=[pl.BlockSpec((block_rows, _WIDTH), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((block_rows, _WIDTH), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(t.shape, jnp.dtype(f"int{cfg.storage_bits}")),
+        name="encode_block",
         interpret=interpret,
     )(t)
     return out.reshape(-1)[:size].reshape(shape)

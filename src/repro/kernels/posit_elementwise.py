@@ -70,6 +70,7 @@ def elementwise(op: str, *inputs, cfg: PositConfig, block_rows: int = 64,
                   for _ in range(n_in)],
         out_specs=pl.BlockSpec((block_rows, width), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, width), dt),
+        name="elementwise",
         interpret=interpret,
     )(*tiled)
     return out.reshape(-1)[:size].reshape(shape)
@@ -132,6 +133,7 @@ def divide(a, b, *, cfg: PositConfig, mode: str = "poly_corrected",
                       pl.BlockSpec((1, lut.shape[1]), lambda i: (0, 0))],
             out_specs=pl.BlockSpec((block_rows, width), lambda i: (i, 0)),
             out_shape=jax.ShapeDtypeStruct((rows, width), dt),
+            name="divide",
             interpret=interpret,
         )(ta, tb, lut)
         return out.reshape(-1)[:size].reshape(shape)
@@ -142,6 +144,7 @@ def divide(a, b, *, cfg: PositConfig, mode: str = "poly_corrected",
                   pl.BlockSpec((block_rows, width), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((block_rows, width), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, width), dt),
+        name="divide",
         interpret=interpret,
     )(ta, tb)
     return out.reshape(-1)[:size].reshape(shape)

@@ -156,6 +156,7 @@ def posit_gemm(a: jnp.ndarray, b: jnp.ndarray, *,
         scratch_shapes=[pltpu.VMEM((bm_, bn_), jnp.float32)],
         compiler_params=compiler_params(*_GEMM_SEMANTICS,
                                         vmem_limit_bytes=_GEMM_VMEM_LIMIT),
+        name="posit_gemm",
         interpret=interpret,
     )(a, b)
     return out[:m, :n]

@@ -157,6 +157,7 @@ def wkv_scan_pallas(r, k, v, logw, u, s0, num_new, *,
         out_shape=(jax.ShapeDtypeStruct((B, H, Tp, dh), jnp.float32),
                    jax.ShapeDtypeStruct((B, H, dh, dh), s0.dtype)),
         compiler_params=compiler_params("parallel", "parallel", "arbitrary"),
+        name="wkv_scan_pallas",
         interpret=interpret,
     )(num_new, r, k, v, logw, u[:, None, :], s0)
     return y[:, :, :T], sf
@@ -248,6 +249,7 @@ def rglru_scan_pallas(a, b, h0, num_new, *,
         out_shape=(jax.ShapeDtypeStruct((B, Tp, d), jnp.float32),
                    jax.ShapeDtypeStruct((B, 1, d), h0.dtype)),
         compiler_params=compiler_params("parallel", "arbitrary"),
+        name="rglru_scan_pallas",
         interpret=interpret,
     )(num_new, a, b, h0[:, None, :])
     return h[:, :T], hf[:, 0]

@@ -12,6 +12,11 @@
     python -m repro.launch.serve --arch smollm-360m --smoke --engine paged \
         --batch 8 --mesh 4x2 --host-devices 8
 
+    # the same run under the JAX profiler: the trace in DIR holds the paged
+    # engine's phase spans (engine.step, engine.schedule, ..., engine.fetch)
+    python -m repro.launch.serve --arch smollm-360m --smoke --engine paged \
+        --profile DIR
+
 Runs PTQ (quant/ptq.py) on freshly-initialized (or checkpointed) weights,
 then serves synthetic traffic.  The paged engine draws mixed prompt lengths
 in [prompt-len/4, prompt-len] so admission/retirement actually interleave.
@@ -19,6 +24,7 @@ in [prompt-len/4, prompt-len] so admission/retirement actually interleave.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import os
 import time
 
@@ -65,6 +71,9 @@ def main():
                          "page_poison=0.02,straggle=0.1' "
                          "(see serving/faults.py)")
     ap.add_argument("--chaos-seed", type=int, default=0)
+    ap.add_argument("--profile", default=None, metavar="DIR",
+                    help="run under jax.profiler.trace(DIR); the paged "
+                         "engine's phase spans land in that trace")
     args = ap.parse_args()
 
     if args.host_devices:
@@ -85,9 +94,11 @@ def main():
     from repro.models.transformer import init_params
     from repro.quant.policy import PositPolicy
     from repro.quant.ptq import quantize_for_serving
-    from repro.serving.engine import PagedServingEngine, generate
+    from repro.serving.engine import PHASES, PagedServingEngine, generate
 
     enable_compile_cache()
+    profile = (jax.profiler.trace(args.profile) if args.profile
+               else contextlib.nullcontext())
     pcfg = {"p8": P8_2, "p16": P16_2}.get(args.posit)
     policy = PositPolicy(weights=pcfg, kv_cache=pcfg) if pcfg else PositPolicy()
     get = configs.get_smoke if args.smoke else configs.get_config
@@ -109,9 +120,10 @@ def main():
                                      (args.batch, args.prompt_len), 0,
                                      cfg.vocab)
         t0 = time.time()
-        out = generate(params, cfg, prompts, args.max_new,
-                       temperature=args.temperature)
-        out.block_until_ready()
+        with profile:
+            out = generate(params, cfg, prompts, args.max_new,
+                           temperature=args.temperature)
+            out.block_until_ready()
         dt = time.time() - t0
         print(f"[serve] generated {out.shape} in {dt:.2f}s "
               f"({args.batch * args.max_new / dt:.1f} tok/s incl. compile)")
@@ -156,7 +168,8 @@ def main():
                                 args.prompt_len + 1))
         reqs.append((rng.integers(0, cfg.vocab, plen), args.max_new))
     t0 = time.time()
-    results = eng.run(reqs)
+    with profile:
+        results = eng.run(reqs)
     dt = time.time() - t0
     n_tok = sum(len(v) for v in results.values())
     stats = eng.stats()
@@ -171,6 +184,8 @@ def main():
           f"{stats.get('straggler_steps', 0)} "
           f"step_latency_ms p50={stats.get('step_latency_p50_ms', 0.0):.1f} "
           f"p99={stats.get('step_latency_p99_ms', 0.0):.1f}")
+    print("[serve] host phases ms: " + " ".join(
+        f"{ph}={stats[f'phase_ms_{ph}']:.1f}" for ph in PHASES))
     if results:
         first = results[min(results)]
         print(f"[serve] rid {min(results)}: {first[:12]}")

@@ -56,6 +56,7 @@ sync on the happy path.  Outcomes and per-request partial tokens live in
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 import functools
 import time
@@ -405,6 +406,11 @@ class RequestOutcome:
 
 OUTCOMES = ("completed", "rejected", "expired", "failed_nar", "failed_fault")
 
+# the engine's host phases (PagedServingEngine._phase): `step` is one
+# scheduler iteration, the next six tile it in order, `submit` is a submit()
+PHASES = ("step", "schedule", "page_in", "batch", "dispatch", "fetch",
+          "commit", "submit")
+
 
 @dataclasses.dataclass
 class _Slot:
@@ -504,6 +510,21 @@ class PagedServingEngine:
         token) — the step then also returns its last-position logits,
         O(max_seqs * vocab) device->host per step.
         For checking logits against a reference path; off in serving.
+
+    Observability: each step() is a profiler step span `engine.step` (its
+    `kind`, prefill or decode, and `seqs` as arguments) whose child spans
+    tile it in order: `engine.schedule` (retire, expire, admit, the
+    page-poison hook), `engine.page_in` (allocation, preemption,
+    copy-on-write and its device copies, the step's token layout),
+    `engine.batch` (NaR-poison flags, the table snapshot, host->device
+    transfers), `engine.dispatch` (the jitted step call), `engine.fetch`
+    (the host waiting on the device for the sampled tokens) and
+    `engine.commit` (seq_lens, window reclamation, emission, NaR failures,
+    prefix registration); submit() is `engine.submit`.  The spans are
+    always on: with no profiler attached each costs one small object.  The
+    same phases keep host-time totals, stats()' `phase_ms_<name>`, and
+    `step_rows` records, per step that ran the device, its kind and the
+    (new tokens, tokens cached before) of every slot it computed.
     """
 
     def __init__(self, params, cfg: ModelConfig, *, max_seqs: int = 8,
@@ -887,7 +908,6 @@ class PagedServingEngine:
         self.counters[status] += 1
         if status == "completed":
             self.finished[req.rid] = toks
-            self.counters["finished"] += 1      # legacy alias
 
     def _fail_slot(self, i: int, status: str, detail: str):
         """Resolve slot i's request as `status` (partial tokens kept) and
@@ -1069,6 +1089,12 @@ class PagedServingEngine:
         steps from now / wall-clock seconds from now); either hitting its
         limit cancels the request (`expired`), returning its pages and
         state slots to the pool.  Defaults come from the engine ctor."""
+        with self._phase("submit"):
+            return self._submit(prompt, max_new, rid, ttl_steps,
+                                deadline_s)
+
+    def _submit(self, prompt, max_new: int, rid: int | None,
+                ttl_steps: int | None, deadline_s: float | None) -> int:
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         if len(prompt) == 0:
             # an empty prompt would enter decode with the -1 sentinel as a
@@ -1128,12 +1154,13 @@ class PagedServingEngine:
 
     # ---- observability ---------------------------------------------------
     def stats(self) -> dict:
-        """Scheduler + prefix-cache counters (the serving bench prints
-        this).  Fallback counters are process-global; they are reported as
-        deltas since engine construction or the last reset_stats()."""
+        """Scheduler + prefix-cache counters and the phase clocks (the
+        serving bench prints this).  Fallback counters are process-global;
+        they are reported as deltas since engine construction or the last
+        reset_stats()."""
         from repro.kernels.ops import RECURRENT_FALLBACKS
         from repro.models.moe import DENSE_MOE_FALLBACKS
-        d = {k: 0 for k in ("admitted", "finished", "preempted",
+        d = {k: 0 for k in ("admitted", "preempted",
                             "prefill_steps", "decode_steps",
                             "prefix_hits", "prefix_misses",
                             "prefix_hit_tokens", "prefix_probe_tokens",
@@ -1163,6 +1190,8 @@ class PagedServingEngine:
             d["step_latency_p99_ms"] = float(lat[1]) * 1e3
         else:
             d["step_latency_p50_ms"] = d["step_latency_p99_ms"] = 0.0
+        for name in PHASES:
+            d[f"phase_ms_{name}"] = self._phase_s[name] * 1e3
         return d
 
     def reset_stats(self):
@@ -1171,10 +1200,28 @@ class PagedServingEngine:
         from repro.kernels.ops import RECURRENT_FALLBACKS
         from repro.models.moe import DENSE_MOE_FALLBACKS
         self.counters.clear()
+        # per step that ran the device: its engine.step phase reading, and
+        # (kind, ((new tokens, tokens cached before) per slot computed))
         self._step_lat_s: collections.deque = collections.deque(maxlen=4096)
+        self.step_rows: collections.deque = collections.deque(maxlen=4096)
+        self._phase_s = dict.fromkeys(PHASES, 0.0)
         self._gather_base = sum(GATHER_FALLBACKS.values())
         self._moe_base = sum(DENSE_MOE_FALLBACKS.values())
         self._rec_base = sum(RECURRENT_FALLBACKS.values())
+
+    @contextlib.contextmanager
+    def _phase(self, name: str, span=None):
+        """Run the block inside the profiler span `engine.<name>` (or
+        `span`, given) and add its host time to phase clock `name`
+        (stats()' `phase_ms_<name>`); yields the span."""
+        if span is None:
+            span = jax.profiler.TraceAnnotation(f"engine.{name}")
+        t0 = time.perf_counter()
+        try:
+            with span:
+                yield span
+        finally:
+            self._phase_s[name] += time.perf_counter() - t0
 
     def _sample_host(self, logits_row: np.ndarray) -> int:
         """Host-side sampling oracle.  The engine samples on device inside
@@ -1208,59 +1255,57 @@ class PagedServingEngine:
 
     def _run_step(self, tokens: np.ndarray, num_new: np.ndarray,
                   participants):
-        """Run the fused step; returns (tokens, nar) — the sampled token
-        and the on-device NaR-detector flag per slot ([max_seqs] int32 /
-        bool, fetched in one transfer, so the happy path costs no extra
-        host sync; under record_logits the step's last-position logits
-        land in self._last_logits).  A step that fails (InjectedFault
-        before the device call) is retried once against unchanged state; a
-        repeat failure quarantines the participants and returns
-        (None, None)."""
-        t_step0 = time.perf_counter()
-        poisoned: list[int] = []
-        if self._chaos is not None:
-            poisoned = self._chaos.poison_slots(self._step_idx, participants)
-        poison = np.zeros((self.max_seqs,), bool)
-        poison[poisoned] = True
-        # snapshot the host tables: on the CPU backend jnp.asarray may alias
-        # a numpy buffer, and the host advances seq_lens and reclaims table
-        # entries while the dispatched step can still be reading them
-        pt = jnp.asarray(np.array(self._table_view(participants)))
-        sl = jnp.asarray(self.seq_lens.copy())
-        nn = jnp.asarray(num_new)
-        for attempt in (0, 1):
-            try:
-                if self._chaos is not None:
-                    nap = self._chaos.straggle(self._step_idx, attempt)
-                    if nap > 0.0:
-                        self.counters["straggler_steps"] += 1
-                        time.sleep(nap)
-                    if self._chaos.step_fault(self._step_idx, attempt):
-                        self.counters["injected_step_faults"] += 1
-                        raise InjectedFault(
-                            f"injected device failure at step "
-                            f"{self._step_idx} attempt {attempt}")
-                toks, bad, self.pages, *last = self._step_fn(
-                    self.params, jnp.asarray(tokens), self.pages, pt, sl, nn,
-                    jnp.float32(self.temperature), jnp.int32(self._seed),
-                    jnp.int32(self._step_idx), jnp.asarray(poison))
-                break
-            except InjectedFault:
-                if attempt == 0:
-                    self.counters["step_retries"] += 1
-                    continue
-                self._quarantine(participants)
-                return None, None
-        self.counters["injected_nar_poisons"] += len(poisoned)
-        self._step_idx += 1
-        self.seq_lens += num_new
-        self._reclaim_expired()
-        toks, bad, last = jax.device_get((toks, bad, last))
+        """Run the fused step, in the batch, dispatch and fetch phases;
+        returns (tokens, nar) — the sampled token and the on-device
+        NaR-detector flag per slot ([max_seqs] int32 / bool, fetched in one
+        transfer, so the happy path costs no extra host sync; under
+        record_logits the step's last-position logits land in
+        self._last_logits).  A step that fails (InjectedFault before the
+        device call) is retried once against unchanged state; a repeat
+        failure quarantines the participants and returns (None, None)."""
+        with self._phase("batch"):
+            poisoned: list[int] = []
+            if self._chaos is not None:
+                poisoned = self._chaos.poison_slots(self._step_idx,
+                                                    participants)
+            poison = np.zeros((self.max_seqs,), bool)
+            poison[poisoned] = True
+            # snapshot the host tables, which the host edits in place: on
+            # the CPU backend jnp.asarray may alias a numpy buffer
+            tok_in = jnp.asarray(tokens)
+            inputs = (jnp.asarray(np.array(self._table_view(participants))),
+                      jnp.asarray(self.seq_lens.copy()), jnp.asarray(num_new),
+                      jnp.float32(self.temperature), jnp.int32(self._seed),
+                      jnp.int32(self._step_idx), jnp.asarray(poison))
+        with self._phase("dispatch"):
+            for attempt in (0, 1):
+                try:
+                    if self._chaos is not None:
+                        nap = self._chaos.straggle(self._step_idx, attempt)
+                        if nap > 0.0:
+                            self.counters["straggler_steps"] += 1
+                            time.sleep(nap)
+                        if self._chaos.step_fault(self._step_idx, attempt):
+                            self.counters["injected_step_faults"] += 1
+                            raise InjectedFault(
+                                f"injected device failure at step "
+                                f"{self._step_idx} attempt {attempt}")
+                    toks, bad, self.pages, *last = self._step_fn(
+                        self.params, tok_in, self.pages, *inputs)
+                    break
+                except InjectedFault:
+                    if attempt == 0:
+                        self.counters["step_retries"] += 1
+                        continue
+                    self._quarantine(participants)
+                    return None, None
+            self.counters["injected_nar_poisons"] += len(poisoned)
+            self._step_idx += 1
+            del tok_in, inputs       # their release is this phase's work
+        with self._phase("fetch"):
+            toks, bad, last = jax.device_get((toks, bad, last))
         if last:
             self._last_logits = np.asarray(last[0])
-        # end-to-end wall time of the fused step (injected straggler sleeps
-        # included — that skew is exactly what the p99 is for)
-        self._step_lat_s.append(time.perf_counter() - t_step0)
         return np.asarray(toks), np.asarray(bad)
 
     def _keep_logits(self, i: int):
@@ -1310,48 +1355,85 @@ class PagedServingEngine:
             return False
 
     def step(self) -> list[tuple[int, int]]:
-        """One scheduler iteration; returns (rid, token) pairs emitted."""
-        # retire finished sequences (before expiry: a request that is done
-        # resolves `completed` even if its deadline passed this instant),
-        # then cancel expired work, then fill freed slots from the queue
-        for i, slot in enumerate(self.slots):
-            if slot is not None and slot.done:
-                self._resolve(slot.req, "completed",
-                              generated=slot.generated)
-                self._free_slot(i)
-        self._expire_deadlines()
-        self._admit()
-        self._maybe_poison_page()
+        """One scheduler iteration; returns (rid, token) pairs emitted.  A
+        step that ran the device adds its engine.step phase reading to the
+        step latencies (injected straggler sleeps included — that skew is
+        exactly what the p99 is for)."""
+        t0 = self._phase_s["step"]
+        span = jax.profiler.StepTraceAnnotation("engine.step",
+                                               step_num=self._step_idx)
+        with self._phase("step", span):
+            emitted = self._step(span)
+        if emitted is None:
+            return []
+        self._step_lat_s.append(self._phase_s["step"] - t0)
+        return emitted
 
-        prefilling = [i for i, s in enumerate(self.slots)
-                      if s is not None and s.phase == "prefill"]
-        emitted: list[tuple[int, int]] = []
-        if prefilling:
+    def _step(self, span):
+        """step()'s phases, in order; None when no device step ran."""
+        with self._phase("schedule"):
+            # retire finished sequences (before expiry: a request that is
+            # done resolves `completed` even if its deadline passed this
+            # instant), then cancel expired work, then fill freed slots from
+            # the queue; prefill steps go first
+            for i, slot in enumerate(self.slots):
+                if slot is not None and slot.done:
+                    self._resolve(slot.req, "completed",
+                                  generated=slot.generated)
+                    self._free_slot(i)
+            self._expire_deadlines()
+            self._admit()
+            self._maybe_poison_page()
+            kind = "prefill"
+            part = [i for i, s in enumerate(self.slots)
+                    if s is not None and s.phase == "prefill"]
+            if not part:
+                kind = "decode"
+                part = [i for i, s in enumerate(self.slots)
+                        if s is not None and s.phase == "decode"
+                        and not s.done]
+        if not part:
+            return None
+        with self._phase("page_in"):
             # page in first: allocation may preempt a slot (even one in
-            # `prefilling`), so the batch is built only from survivors.
+            # `part`), so the batch is built only from survivors.
             # _maybe_cow runs after paging: a warm slot resuming mid-page
             # (fully cached page-aligned prompt) must write into a private
             # copy, never the shared page.
-            for i in prefilling:
-                if self.slots[i] is None:
-                    continue
-                self._page_in(i)
-            alive = [i for i in prefilling if self.slots[i] is not None]
-            if not alive:
-                return emitted
-            tokens = np.zeros((self.max_seqs, self.chunk), np.int32)
+            for i in part:
+                if self.slots[i] is not None:
+                    self._page_in(i)
+            part = [i for i in part if self.slots[i] is not None]
+            width = self.chunk if kind == "prefill" else 1
+            tokens = np.zeros((self.max_seqs, width), np.int32)
             num_new = np.zeros((self.max_seqs,), np.int32)
-            for i in alive:
+            for i in part:
                 s = self.slots[i]
-                part = s.req.prompt[s.prefill_pos:s.prefill_pos + self.chunk]
-                tokens[i, :len(part)] = part
-                num_new[i] = len(part)
-            toks, bad = self._run_step(tokens, num_new, alive)
-            if toks is None:
-                return emitted           # step failed twice: slots resolved
-            for i in alive:
+                if kind == "prefill":
+                    seg = s.req.prompt[s.prefill_pos:s.prefill_pos + width]
+                    tokens[i, :len(seg)] = seg
+                    num_new[i] = len(seg)
+                else:
+                    tokens[i, 0] = s.next_token
+                    num_new[i] = 1
+        if not part:
+            return None
+        span.set_metadata(kind=kind, seqs=len(part))
+        toks, bad = self._run_step(tokens, num_new, part)
+        if toks is None:
+            return None                  # step failed twice: slots resolved
+        with self._phase("commit"):
+            # whole-array reads: no Python call per slot, which a profiler
+            # with its Python tracer on would record and the step would pay
+            self.step_rows.append((kind, tuple(zip(
+                num_new[part].tolist(), self.seq_lens[part].tolist()))))
+            self.seq_lens += num_new
+            self._reclaim_expired()
+            emitted: list[tuple[int, int]] = []
+            for i in part:
                 s = self.slots[i]
-                s.prefill_pos += int(num_new[i])
+                if kind == "prefill":
+                    s.prefill_pos += int(num_new[i])
                 if bad[i]:
                     # NaR reached this slot's logits: fail it before any
                     # token is emitted or any page registers in the prefix
@@ -1359,47 +1441,15 @@ class PagedServingEngine:
                     self._fail_slot(i, "failed_nar",
                                     "NaR detected in output logits")
                     continue
-                if s.phase == "decode":
+                # decoding, or its prompt just done
+                if kind == "decode" or s.phase == "decode":
                     self._keep_logits(i)
                     tok = int(toks[i])
                     s.generated.append(tok)
                     s.next_token = tok
                     emitted.append((s.req.rid, tok))
                 self._register(i)
-            self.counters["prefill_steps"] += 1
-            return emitted
-
-        decoding = [i for i, s in enumerate(self.slots)
-                    if s is not None and s.phase == "decode" and not s.done]
-        if not decoding:
-            return emitted
-        for i in decoding:
-            if self.slots[i] is not None:
-                self._page_in(i)
-        decoding = [i for i in decoding if self.slots[i] is not None]
-        if not decoding:
-            return emitted
-        tokens = np.zeros((self.max_seqs, 1), np.int32)
-        num_new = np.zeros((self.max_seqs,), np.int32)
-        for i in decoding:
-            tokens[i, 0] = self.slots[i].next_token
-            num_new[i] = 1
-        toks, bad = self._run_step(tokens, num_new, decoding)
-        if toks is None:
-            return emitted
-        for i in decoding:
-            s = self.slots[i]
-            if bad[i]:
-                self._fail_slot(i, "failed_nar",
-                                "NaR detected in output logits")
-                continue
-            self._keep_logits(i)
-            tok = int(toks[i])
-            s.generated.append(tok)
-            s.next_token = tok
-            emitted.append((s.req.rid, tok))
-            self._register(i)
-        self.counters["decode_steps"] += 1
+            self.counters[f"{kind}_steps"] += 1
         return emitted
 
     def run(self, requests=None, max_steps: int | None = None

@@ -13,6 +13,7 @@ from __future__ import annotations
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -107,3 +108,45 @@ def test_kernel_compiles_for_v5e(one_chip, kernel, shapes, static):
             for s, dt in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# the op name the device trace gives each kernel: its pallas_call's `name`
+OP_NAMES = {
+    "pw_gemm-p8-decode": "posit_gemm", "pw_gemm-p16-decode": "posit_gemm",
+    "pw_gemm-p8-unembed": "posit_gemm", "pw_gemm-p16-unembed": "posit_gemm",
+    "paged_flash_decode-p16": "paged_flash_decode",
+    "paged_flash_prefill-p16": "paged_flash_prefill",
+    "flash_prefill_fwd": "flash_prefill_contiguous",
+    "flash_prefill_bwd": "flash_prefill_bwd_contiguous",
+    "encode_block-p16": "encode_block",
+    "elementwise_add-p16": "elementwise",
+    "posit_grouped_gemm-p16": "posit_grouped_gemm",
+    "wkv_scan-p16": "wkv_scan_pallas",
+    "rglru_scan-p16": "rglru_scan_pallas",
+}
+
+
+def _unjitted(kernel):
+    """The kernel's Python body without its own jit."""
+    if isinstance(kernel, functools.partial):
+        return functools.partial(_unjitted(kernel.func), *kernel.args,
+                                 **kernel.keywords)
+    return getattr(kernel, "__wrapped__", kernel)
+
+
+@pytest.mark.parametrize("case,kernel,shapes,static", CASES,
+                         ids=[c[0] for c in CASES])
+def test_kernel_op_keeps_its_name(one_chip, case, kernel, shapes, static):
+    """Called from a jitted function of another name, each kernel's op
+    keeps the name the device trace reads (a pallas_call without `name`
+    takes its enclosing jit's), so renaming a wrapper renames no op."""
+    def renamed(*a):
+        return _unjitted(kernel)(*a, **static)
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in shapes]
+    text = jax.jit(renamed).lower(*args).compile().as_text()
+    ops = re.findall(r"%([\w.-]+) = [^\n]*custom_call_target="
+                     r"\"tpu_custom_call\"", text)
+    assert ops
+    for op in ops:
+        assert re.fullmatch(rf"{OP_NAMES[case]}(\.\d+)?", op), op

@@ -396,12 +396,12 @@ def test_stats_surface_and_reset():
     eng = _engine(params, cfg)
     eng.run([(np.arange(6, dtype=np.int32), 3)])
     st = eng.stats()
-    for key in ("admitted", "finished", "preempted", "prefix_hits",
+    for key in ("admitted", "completed", "preempted", "prefix_hits",
                 "prefix_misses", "prefix_hit_tokens", "evicted_pages",
                 "cow_copies", "deduped_pages", "gather_fallbacks",
                 "dense_moe_fallbacks", "free_pages", "cached_pages"):
         assert key in st, key
-    assert st["admitted"] == 1 and st["finished"] == 1
+    assert st["admitted"] == 1 and st["completed"] == 1
     eng.reset_stats()
     st = eng.stats()
     assert st["admitted"] == 0 and st["gather_fallbacks"] == 0

@@ -234,7 +234,7 @@ def test_engine_slot_reuse_more_requests_than_slots():
                                table_width=8, prefill_chunk=8)
     res = eng.run([(np.asarray(prompts[i % 4]), max_new) for i in range(6)])
     assert sorted(res) == list(range(6))
-    assert eng.counters["finished"] == 6 and eng.active == 0
+    assert eng.counters["completed"] == 6 and eng.active == 0
     # every page is either back on the free stack or resident in the
     # prefix cache (idle, evictable) — none leaked, none doubly owned
     assert len(eng.free_pages) + eng.cached_pages == eng.num_pages - 1

@@ -26,7 +26,9 @@ Three fused entry points share the decode-before-the-MXU structure:
 Every grid is tagged with `dimension_semantics`: batch and q-tile axes are
 "parallel" (no cross-iteration state), the KV axis is "arbitrary" (it
 carries the online-softmax running max/sum/acc), which lets Mosaic
-parallelize across cores without breaking the accumulation order.
+parallelize across cores without breaking the accumulation order.  The
+decode kernel has no KV grid axis: each sequence's grid step loops over
+only its live blocks of pages, copying them from the pool itself.
 """
 from __future__ import annotations
 
@@ -34,6 +36,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -90,65 +93,200 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
         o_ref[0] = acc_ref[...] / jnp.where(l == 0, 1.0, l)
 
 
-def _paged_decode_kernel(pt_ref, sl_ref, q_ref, k_ref, v_ref, o_ref,
-                         m_ref, l_ref, acc_ref, *, cfg_kv, n_kv, groups,
-                         page, n_pages, scale, window):
-    """One (sequence, page) cell of the paged decode grid.
+def decode_pages_per_block(page: int, width: int) -> int:
+    """Pages paged_flash_decode copies and computes at once: a block of
+    about 128 tokens, never more pages than the table is wide."""
+    return min(width, max(1, 128 // page))
 
-    The page index was resolved by the BlockSpec index_map from the
-    prefetched page table, so k_ref/v_ref already hold this sequence's
-    j-th KV page in VMEM; posit pages decode here, right before the dot —
-    HBM only ever saw the narrow ints.
+
+def _live_blocks(seq_len, bt, window, maximum):
+    """[first, end) of the bt-token blocks that hold a position the decode
+    query attends, [seq_len - window, seq_len): empty at seq_len 0.
+    `maximum` is np.maximum on the host and jnp.maximum in the kernel."""
+    end = (seq_len + bt - 1) // bt
+    first = 0 if window is None else maximum(seq_len - window, 0) // bt
+    return first, end
+
+
+def decode_blocks_run(seq_lens, *, page: int, width: int,
+                      window: int | None = None) -> np.ndarray:
+    """Blocks paged_flash_decode runs for each post-append length of
+    `seq_lens` (host numpy; the kernel's own loop bounds)."""
+    bt = page * decode_pages_per_block(page, width)
+    first, end = _live_blocks(np.asarray(seq_lens, np.int64), bt, window,
+                              np.maximum)
+    return end - first
+
+
+def _decode_lanes(d: int, page: int, n_kv: int) -> tuple[int, int]:
+    """(fold, dp): how paged_flash_decode lays a page's rows across lanes.
+
+    A DMA may slice the pool only along whole tiles (128 lanes by 8 rows),
+    so each page is viewed as rows of fold * dp lanes: `fold` consecutive
+    tokens of one KV head, each `dp` lanes wide.  A head dim that divides
+    128 folds (fold = 128 // d: at d=64 a row holds two tokens) where the
+    page then has a multiple of 8 rows; a multiple of 128 stands as it
+    is; any other is zero-padded to the next multiple of 128, which costs
+    a copy of the pool."""
+    if d % 128 == 0:
+        return 1, d
+    fold = 128 // d
+    if 128 % d == 0 and page % fold == 0 and n_kv * page // fold % 8 == 0:
+        return fold, d
+    return 1, -(-d // 128) * 128
+
+
+def _paged_decode_kernel(pt_ref, sl_ref, q_ref, k_hbm, v_hbm, o_ref,
+                         k_buf, v_buf, sems, m_ref, l_ref, acc_ref, *,
+                         cfg_kv, n_kv, groups, page, ppb, width, fold, dp,
+                         scale, window):
+    """One sequence of the paged decode grid.
+
+    The loop runs only the blocks of `ppb` pages that hold a live token
+    (_live_blocks of the prefetched seq_lens), so a page past the
+    sequence's length, or wholly before its window, is neither copied nor
+    computed.  Each block's pages are copied from the pool in HBM into one
+    of two VMEM buffers, one copy per page for K and one for V, and the
+    next block's copies start before the current block is computed.  Posit
+    pages decode here, right before the dot: HBM only ever saw the narrow
+    ints.
+
+    A page is R = n_kv * rph rows of fold * dp lanes (_decode_lanes): row
+    r holds tokens (r % rph) * fold + f, f < fold, of KV head r // rph.  So
+    the scores of lane block f are one dot of q, placed in lane block f
+    (q_ref[0, f]), with the rows of all the block's pages stacked; a score
+    whose row belongs to another KV head than the query's is masked, and
+    the value dot keeps lane block f of its product.
     """
     b = pl.program_id(0)
-    j = pl.program_id(1)
+    seq_len = sl_ref[b]
+    bt = ppb * page
+    first, end = _live_blocks(seq_len, bt, window, jnp.maximum)
+    H = n_kv * groups
+    _, R, lanes = k_buf.shape[1:]
+    rph = R // n_kv
 
-    @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, _NEG)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    def copies(blk, slot):
+        """(live, K copy, V copy) of each page of block blk into buffer
+        slot; a page past the table, past seq_len or before the window is
+        not live and is never copied."""
+        out = []
+        for i in range(ppb):
+            j = blk * ppb + i
+            live = (j < width) & (j * page < seq_len)
+            if window is not None:
+                live &= (j + 1) * page > seq_len - window
+            pg = pt_ref[b, jnp.minimum(j, width - 1)]
+            out.append((live,
+                        pltpu.make_async_copy(k_hbm.at[pg],
+                                              k_buf.at[slot, i],
+                                              sems.at[0, slot]),
+                        pltpu.make_async_copy(v_hbm.at[pg],
+                                              v_buf.at[slot, i],
+                                              sems.at[1, slot])))
+        return out
 
-    d = q_ref.shape[-1]
-    q = q_ref[0].astype(jnp.float32).reshape(n_kv, groups, d)
-    k = k_ref[0]
-    v = v_ref[0]
-    if cfg_kv is not None:
-        k = decode_to_f32(k, cfg_kv)
-        v = decode_to_f32(v, cfg_kv)
-    else:
-        k = k.astype(jnp.float32)
-        v = v.astype(jnp.float32)
+    def start(blk, slot):
+        for live, ck, cv in copies(blk, slot):
+            @pl.when(live)
+            def _():
+                ck.start()
+                cv.start()
 
-    # s[kv, g, p] = q[kv, g, :] . k[kv, p, :]  (batched over the kv head)
-    s = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
-                            preferred_element_type=jnp.float32) * scale
-    kpos = j * page + jax.lax.broadcasted_iota(jnp.int32,
-                                               (n_kv, groups, page), 2)
-    valid = kpos < sl_ref[b]
-    if window is not None:
-        # local attention: the query sits at position sl-1 (the cache is
-        # post-append), so it sees kpos in (sl-1-window, sl) — identical to
-        # the blockwise decode path's `qpos - kpos < window` mask
-        valid = valid & (kpos > sl_ref[b] - 1 - window)
-    s = jnp.where(valid, s, _NEG)
+    def wait(blk, slot):
+        for live, ck, cv in copies(blk, slot):
+            @pl.when(live)
+            def _():
+                ck.wait()
+                cv.wait()
 
-    m_prev = m_ref[...][:, :, :1]                     # (n_kv, groups, 1)
-    m_cur = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-    p = jnp.exp(s - m_cur)
-    alpha = jnp.exp(m_prev - m_cur)
-    l_ref[...] = l_ref[...] * alpha + jnp.broadcast_to(
-        p.sum(axis=-1, keepdims=True), l_ref.shape)
-    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-        p, v, (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)
-    m_ref[...] = jnp.broadcast_to(m_cur, m_ref.shape)
+    def attended(pos):
+        ok = pos < seq_len
+        if window is not None:
+            # local attention: the query sits at position seq_len-1 (the
+            # cache is post-append), so it sees pos in (seq_len-1-window,
+            # seq_len), as the blockwise decode path's `qpos - kpos <
+            # window` mask does
+            ok &= pos > seq_len - 1 - window
+        return ok
 
-    @pl.when(j == n_pages - 1)
-    def _done():
-        l = l_ref[...][:, :, :1]
-        out = acc_ref[...] / jnp.where(l == 0, 1.0, l)
-        o_ref[0] = out.reshape(n_kv * groups, d)
+    # block-invariant offsets: each element's position past the block's
+    # first, and each score's KV head against its query's.  The block's
+    # pages stack into n = ppb * R rows: row c is row c % R of page c // R
+    n = ppb * R
+    row = jax.lax.broadcasted_iota(jnp.int32, (n, lanes), 0)
+    kv_off = ((row // R) * page + (row % R % rph) * fold
+              + jax.lax.broadcasted_iota(jnp.int32, (n, lanes), 1) // dp)
+    col = jax.lax.broadcasted_iota(jnp.int32, (H, n), 1)
+    s_off = (col // R) * page + (col % R % rph) * fold
+    same_head = (jax.lax.broadcasted_iota(jnp.int32, (H, n), 0) // groups
+                 == col % R // rph)
+    lane_fold = jax.lax.broadcasted_iota(jnp.int32, (H, lanes), 1) // dp
+    qs = [q_ref[0, f].astype(jnp.float32) for f in range(fold)]
+
+    m_ref[...] = jnp.full_like(m_ref, _NEG)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(first < end)
+    def _():
+        start(first, 0)
+
+    def block(blk, carry):
+        slot = (blk - first) % 2
+
+        @pl.when(blk + 1 < end)
+        def _():
+            start(blk + 1, 1 - slot)
+
+        wait(blk, slot)
+        k = k_buf[slot]                               # (ppb, R, lanes)
+        v = v_buf[slot]
+        if cfg_kv is not None:
+            k = decode_to_f32(k, cfg_kv)
+            v = decode_to_f32(v, cfg_kv)
+        else:
+            k = k.astype(jnp.float32)
+            v = v.astype(jnp.float32)
+        k, v = k.reshape(n, lanes), v.reshape(n, lanes)
+        # a masked token's p is 0, but a page that was not copied, or the
+        # garbage tail of a live one, may hold NaN: 0 * NaN is NaN, and a
+        # folded row shares its lanes' dot with a live token
+        live = attended(blk * bt + kv_off)
+        k = jnp.where(live, k, 0.0)
+        v = jnp.where(live, v, 0.0)
+
+        # s[f][h, c]: query head h against stacked row c, token
+        # (c % R % rph) * fold + f of KV head c % R // rph of page c // R
+        s = []
+        for f in range(fold):
+            sf = jax.lax.dot_general(
+                qs[f], k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            ok = same_head & attended(blk * bt + s_off + f)
+            s.append(jnp.where(ok, sf, _NEG))
+        m_prev = m_ref[...][:, :1]                    # (H, 1)
+        m_cur = m_prev
+        for sf in s:
+            m_cur = jnp.maximum(m_cur, sf.max(axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_cur)
+        l_new = l_ref[...][:, :1] * alpha
+        acc = acc_ref[...] * alpha
+        for f, sf in enumerate(s):
+            p = jnp.exp(sf - m_cur)
+            l_new += p.sum(axis=1, keepdims=True)
+            pv = jax.lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            acc += jnp.where(lane_fold == f, pv, 0.0)
+        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+        acc_ref[...] = acc
+        m_ref[...] = jnp.broadcast_to(m_cur, m_ref.shape)
+        return carry
+
+    jax.lax.fori_loop(first, end, block, 0)
+    l = l_ref[...][:, :1]
+    o_ref[0] = acc_ref[...] / jnp.where(l == 0, 1.0, l)
 
 
 def _prefill_body(sl_ref, qo_ref, q_ref, k_ref, v_ref, o_ref, *rest,
@@ -669,52 +807,72 @@ def paged_flash_decode(q: jnp.ndarray, k_pages: jnp.ndarray,
                        interpret: bool = False) -> jnp.ndarray:
     """Fused paged-gather decode attention (the continuous-batching hot path).
 
-    q [B, H, D] x paged KV pool -> [B, H, D].  k_pages/v_pages
-    [num_pages, n_kv, page, D] hold posit storage ints when cfg_kv is set;
-    page_table [B, W] names each sequence's pages in position order and is
-    scalar-prefetched so the BlockSpec index_map can stream exactly the
-    pages a sequence owns — the dense `materialize_kv` copy never exists.
-    Positions >= seq_lens[b] (garbage-page tails, unallocated entries) are
-    masked.  GQA: H = n_kv * groups, query head h reads kv head h // groups.
-    window: sliding-window (local-attention) size — the decode query at
-    position seq_lens[b]-1 attends only the last `window` tokens.  Pages
-    entirely outside the window still stream (the grid is static over W);
-    their scores are masked to -inf, matching the gathered reference.
+    q [B, H, D] x paged KV pool -> [B, H, D] f32.  k_pages/v_pages
+    [num_pages, n_kv, page, D] hold posit storage ints when cfg_kv is set
+    and stay in HBM; page_table [B, W] names each sequence's pages in
+    position order and is scalar-prefetched with seq_lens, so the kernel
+    copies exactly the pages a sequence owns — the dense `materialize_kv`
+    copy never exists.  One grid step per sequence walks its live blocks of
+    decode_pages_per_block(page, W) pages (~128 tokens): positions >=
+    seq_lens[b] are masked, and a block with none of the sequence's
+    attended positions is neither copied nor computed (decode_blocks_run
+    counts them).  GQA: H = n_kv * groups, query head h reads kv head
+    h // groups.  window: sliding-window (local-attention) size — the
+    decode query at position seq_lens[b]-1 attends only the last `window`
+    tokens, and blocks wholly before them are skipped too.
     """
     bh, H, d = q.shape
-    n_pages_total, n_kv, page, _ = k_pages.shape
+    n_pool, n_kv, page, _ = k_pages.shape
     _, W = page_table.shape
     groups = H // n_kv
-    scale = 1.0 / (d ** 0.5)
-    grid = (bh, W)
+    ppb = decode_pages_per_block(page, W)
+    fold, dp = _decode_lanes(d, page, n_kv)
+    if dp != d:
+        pad = ((0, 0),) * 3 + ((0, dp - d),)
+        k_pages, v_pages = jnp.pad(k_pages, pad), jnp.pad(v_pages, pad)
+        q = jnp.pad(q, pad[1:])
+    # the pool as rows of fold * dp lanes; q once per lane block f
+    R, lanes = n_kv * page // fold, fold * dp
+    k_rows = k_pages.reshape(n_pool, R, lanes)
+    v_rows = v_pages.reshape(n_pool, R, lanes)
+    qf = jnp.stack([jnp.pad(q, ((0, 0), (0, 0),
+                                (f * dp, lanes - (f + 1) * dp)))
+                    for f in range(fold)], axis=1)       # (B, fold, H, lanes)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=grid,
+        grid=(bh,),
         in_specs=[
-            pl.BlockSpec((1, H, d), lambda b, j, pt, sl: (b, 0, 0)),
-            pl.BlockSpec((1, n_kv, page, d),
-                         lambda b, j, pt, sl: (pt[b, j], 0, 0, 0)),
-            pl.BlockSpec((1, n_kv, page, d),
-                         lambda b, j, pt, sl: (pt[b, j], 0, 0, 0)),
+            pl.BlockSpec((1, fold, H, lanes), lambda b, pt, sl: (b, 0, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, H, d), lambda b, j, pt, sl: (b, 0, 0)),
+        out_specs=pl.BlockSpec((1, H, lanes), lambda b, pt, sl: (b, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((n_kv, groups, 128), jnp.float32),
-            pltpu.VMEM((n_kv, groups, 128), jnp.float32),
-            pltpu.VMEM((n_kv, groups, d), jnp.float32),
+            pltpu.VMEM((2, ppb, R, lanes), k_pages.dtype),
+            pltpu.VMEM((2, ppb, R, lanes), v_pages.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.VMEM((H, 128), jnp.float32),
+            pltpu.VMEM((H, 128), jnp.float32),
+            pltpu.VMEM((H, lanes), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_paged_decode_kernel, cfg_kv=cfg_kv, n_kv=n_kv,
-                          groups=groups, page=page, n_pages=W, scale=scale,
+                          groups=groups, page=page, ppb=ppb, width=W,
+                          fold=fold, dp=dp, scale=1.0 / (d ** 0.5),
                           window=window),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((bh, H, d), jnp.float32),
-        compiler_params=compiler_params("parallel", "arbitrary"),
+        out_shape=jax.ShapeDtypeStruct((bh, H, lanes), jnp.float32),
+        compiler_params=compiler_params("parallel"),
         name="paged_flash_decode",
         interpret=interpret,
-    )(page_table, seq_lens, q, k_pages, v_pages)
+    )(page_table, seq_lens, qf, k_rows, v_rows)
+    # lane block f holds the tokens of fold f: their sum is the output
+    o = out[..., :d]
+    for f in range(1, fold):
+        o = o + out[..., f * dp:f * dp + d]
+    return o
 
 
 @functools.partial(

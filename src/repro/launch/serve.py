@@ -184,8 +184,11 @@ def main():
           f"{stats.get('straggler_steps', 0)} "
           f"step_latency_ms p50={stats.get('step_latency_p50_ms', 0.0):.1f} "
           f"p99={stats.get('step_latency_p99_ms', 0.0):.1f}")
+    run, grid = stats["decode_kv_blocks_run"], stats["decode_kv_blocks_grid"]
     print("[serve] host phases ms: " + " ".join(
-        f"{ph}={stats[f'phase_ms_{ph}']:.1f}" for ph in PHASES))
+        f"{ph}={stats[f'phase_ms_{ph}']:.1f}" for ph in PHASES)
+        + f" | decode kv blocks run/grid={run}/{grid}"
+        + (f" ({run / grid:.1%})" if grid else ""))
     if results:
         first = results[min(results)]
         print(f"[serve] rid {min(results)}: {first[:12]}")

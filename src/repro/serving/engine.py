@@ -66,6 +66,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.flash_attention import (decode_blocks_run,
+                                           decode_pages_per_block)
 from repro.models.transformer import (ModelConfig, assemble_paged_caches,
                                       copy_paged_pages, extract_paged_pages,
                                       forward, init_caches, init_paged_pages,
@@ -680,6 +682,16 @@ class PagedServingEngine:
             if (attn_kinds and all(k == "attn_local" for k in attn_kinds)
                 and cfg.window and self._prefix is None)
             else None)
+        # attention layers per window that decode through paged_flash_decode
+        # (stats()' decode_kv_blocks_*); none where decode takes the jnp path
+        from repro.kernels import ops as kops
+        self._decode_windows = collections.Counter(
+            cfg.window if k == "attn_local" else None
+            for k in (cfg.block_pattern[i % len(cfg.block_pattern)]
+                      for i in range(cfg.n_layers))
+            if k in ("attn", "attn_local")
+        ) if kops.use_pallas() and not kops.force_reference() else {}
+        self._step_width = table_width
         self.reset_stats()
 
         greedy = temperature <= 0.0
@@ -1174,7 +1186,9 @@ class PagedServingEngine:
                             "scrubbed_pages",
                             "straggler_steps", "injected_step_faults",
                             "injected_nar_poisons",
-                            "injected_page_poisons")}
+                            "injected_page_poisons",
+                            "decode_kv_blocks_run",
+                            "decode_kv_blocks_grid")}
         d.update(self.counters)
         d["gather_fallbacks"] = (sum(GATHER_FALLBACKS.values())
                                  - self._gather_base)
@@ -1273,7 +1287,9 @@ class PagedServingEngine:
             # snapshot the host tables, which the host edits in place: on
             # the CPU backend jnp.asarray may alias a numpy buffer
             tok_in = jnp.asarray(tokens)
-            inputs = (jnp.asarray(np.array(self._table_view(participants))),
+            table = np.array(self._table_view(participants))
+            self._step_width = table.shape[1]
+            inputs = (jnp.asarray(table),
                       jnp.asarray(self.seq_lens.copy()), jnp.asarray(num_new),
                       jnp.float32(self.temperature), jnp.int32(self._seed),
                       jnp.int32(self._step_idx), jnp.asarray(poison))
@@ -1307,6 +1323,23 @@ class PagedServingEngine:
         if last:
             self._last_logits = np.asarray(last[0])
         return np.asarray(toks), np.asarray(bad)
+
+    def _count_decode_blocks(self):
+        """stats()' decode_kv_blocks_run: the blocks of pages that this
+        decode step's paged_flash_decode ran, over every attention layer
+        (seq_lens is post-append here, as the kernel saw it); and
+        decode_kv_blocks_grid: the blocks that a static grid of such
+        blocks over every slot's whole table view would run.  On a mesh
+        each device of the model axis runs these blocks for its KV
+        heads."""
+        w = self._step_width
+        blocks = -(-w // decode_pages_per_block(self.page, w))
+        for window, layers in self._decode_windows.items():
+            run = decode_blocks_run(self.seq_lens, page=self.page, width=w,
+                                    window=window)
+            self.counters["decode_kv_blocks_run"] += layers * int(run.sum())
+            self.counters["decode_kv_blocks_grid"] += (layers * blocks
+                                                       * self.max_seqs)
 
     def _keep_logits(self, i: int):
         """record_logits: keep slot i's last-step logits, which produced the
@@ -1428,6 +1461,8 @@ class PagedServingEngine:
             self.step_rows.append((kind, tuple(zip(
                 num_new[part].tolist(), self.seq_lens[part].tolist()))))
             self.seq_lens += num_new
+            if kind == "decode" and self._decode_windows:
+                self._count_decode_blocks()
             self._reclaim_expired()
             emitted: list[tuple[int, int]] = []
             for i in part:

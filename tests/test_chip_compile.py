@@ -69,6 +69,12 @@ CASES = [
      [((8, H, HD), F32), ((64, N_KV, PAGE, HD), I16),
       ((64, N_KV, PAGE, HD), I16), ((8, 33), I32), ((8,), I32)],
      {"cfg_kv": P16_2}),
+    # the offline cell's decode: 64 sequences over a table of width 128
+    # and a pool of 64 x 128 pages and the null page
+    ("paged_flash_decode-p16-cell", fa.paged_flash_decode,
+     [((64, H, HD), F32), ((8193, N_KV, PAGE, HD), I16),
+      ((8193, N_KV, PAGE, HD), I16), ((64, 128), I32), ((64,), I32)],
+     {"cfg_kv": P16_2}),
     ("paged_flash_prefill-p16", fa.paged_flash_prefill,
      [((8, H, 128, HD), F32), ((64, N_KV, PAGE, HD), I16),
       ((64, N_KV, PAGE, HD), I16), ((8, 33), I32), ((8,), I32),
@@ -115,6 +121,7 @@ OP_NAMES = {
     "pw_gemm-p8-decode": "posit_gemm", "pw_gemm-p16-decode": "posit_gemm",
     "pw_gemm-p8-unembed": "posit_gemm", "pw_gemm-p16-unembed": "posit_gemm",
     "paged_flash_decode-p16": "paged_flash_decode",
+    "paged_flash_decode-p16-cell": "paged_flash_decode",
     "paged_flash_prefill-p16": "paged_flash_prefill",
     "flash_prefill_fwd": "flash_prefill_contiguous",
     "flash_prefill_bwd": "flash_prefill_bwd_contiguous",

@@ -141,7 +141,44 @@ def test_phase_clocks_without_a_profiler_and_reset():
     assert len(eng._step_lat_s) == len(eng.step_rows) == len(taken)
     assert [list(rows) for _, rows in eng.step_rows] == taken
     assert st["step_latency_p99_ms"] >= st["step_latency_p50_ms"] > 0
+    # decode took the jnp path here: no decode kernel ran
+    assert st["decode_kv_blocks_run"] == st["decode_kv_blocks_grid"] == 0
     eng.reset_stats()
     st = eng.stats()
     assert all(st[f"phase_ms_{ph}"] == 0.0 for ph in E.PHASES)
     assert not eng.step_rows and st["step_latency_p50_ms"] == 0.0
+
+
+def test_decode_kv_block_counters(monkeypatch):
+    """stats()' decode_kv_blocks_run counts the blocks of pages the decode
+    kernel ran: for each sequence of each decode step, the blocks of
+    decode_pages_per_block pages its length (after the step's token)
+    reaches, over every layer; decode_kv_blocks_grid counts the blocks of
+    a grid over every slot's whole table."""
+    from repro.kernels.flash_attention import decode_pages_per_block
+    monkeypatch.setenv("REPRO_USE_PALLAS", "1")
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
+    cfg = ModelConfig(name="tst", n_layers=2, d_model=32, n_heads=4, n_kv=2,
+                      d_ff=64, vocab=50, policy=PositPolicy(kv_cache=P16_2))
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    page, width, slots = 16, 24, 3
+    eng = E.PagedServingEngine(params, cfg, max_seqs=slots, page_size=page,
+                               table_width=width, prefill_chunk=64,
+                               bucket_pages=False)
+    rng = np.random.default_rng(4)
+    # lengths that stay inside the first 128-token block, cross into the
+    # second one during decode, and start past it
+    _drain(eng, [(rng.integers(0, 50, n).astype(np.int32), 6)
+                 for n in (60, 126, 200)])
+    st = eng.stats()
+    block = page * decode_pages_per_block(page, width)
+    decode = [rows for kind, rows in eng.step_rows if kind == "decode"]
+    assert len(decode) >= 4
+    assert st["decode_kv_blocks_run"] == cfg.n_layers * sum(
+        -(-(cached + new) // block) for rows in decode
+        for new, cached in rows)
+    assert st["decode_kv_blocks_grid"] == (
+        cfg.n_layers * len(decode) * slots * -(-width // (block // page)))
+    assert 0 < st["decode_kv_blocks_run"] < st["decode_kv_blocks_grid"]
+    eng.reset_stats()
+    assert eng.stats()["decode_kv_blocks_run"] == 0

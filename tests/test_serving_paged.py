@@ -62,73 +62,149 @@ def test_paged_vs_dense_logits_bit_exact(pcfg):
     assert jnp.array_equal(ld2, lp2), "decode logits diverge"
 
 
-@pytest.mark.parametrize("pcfg", [None, P16_2, P8_2],
-                         ids=["float", "p16", "p8"])
-def test_paged_flash_decode_vs_materialized_dense_attention(pcfg):
-    """The Pallas paged-gather decode kernel (interpret mode) vs the
-    materialize_kv + dense flash-attention oracle at mixed lengths."""
+def _decode_case(pcfg, *, seq_lens, n_kv=2, G=2, D=16, page=8, W=4,
+                 shuffled=False, nar_pages=False, window=None, seed=3):
+    """q, K/V pools and a page table for paged_flash_decode.  shuffled: the
+    table names the pool's pages in a random order.  nar_pages: every entry
+    past a sequence's length, or wholly before its window, names one page
+    of NaR (NaN for float) bits, which a kernel that reads it cannot hide;
+    the clean table is returned beside it for the reference."""
     from repro.core.convert import f32_to_posit
+
+    rng = np.random.default_rng(seed)
+    B = len(seq_lens)
+    seq_lens = np.asarray(seq_lens, np.int32)
+    pages = 1 + np.arange(B * W)
+    if shuffled:
+        pages = rng.permutation(pages)
+    clean = pt = pages.reshape(B, W).astype(np.int32)
+    n_pool = 1 + B * W
+    if nar_pages:
+        start = np.arange(W)[None] * page
+        dead = start >= seq_lens[:, None]
+        if window is not None:
+            dead |= start + page <= seq_lens[:, None] - window
+        pt = np.where(dead, n_pool, pt).astype(np.int32)
+        n_pool += 1
+    kd = rng.normal(size=(n_pool, n_kv, page, D)).astype(np.float32)
+    vd = rng.normal(size=(n_pool, n_kv, page, D)).astype(np.float32)
+    q = jnp.asarray(rng.normal(size=(B, n_kv * G, D)).astype(np.float32))
+    if pcfg is not None:
+        enc = jax.jit(f32_to_posit, static_argnums=1)
+        kp, vp = enc(jnp.asarray(kd), pcfg), enc(jnp.asarray(vd), pcfg)
+    else:
+        kp, vp = jnp.asarray(kd), jnp.asarray(vd)
+    if nar_pages:
+        bad = jnp.iinfo(kp.dtype).min if pcfg is not None else jnp.nan
+        kp, vp = kp.at[-1].set(bad), vp.at[-1].set(bad)
+    return q, kp, vp, pt, clean, seq_lens
+
+
+# Cases beyond the original mixed-length ones.  decode_pages_per_block
+# pages make a block of ~128 tokens: the lengths cover 0, 1, a block
+# boundary, one past it and the whole table; the widths are ones the block
+# does not divide (8 pages into 20 or 12, 4 into 5); page sizes 8, 16 and
+# 32; groups 1 and 3; head dims that fold two or four tokens into a
+# 128-lane row (64, 32), that fill one (128) and that are padded to one
+# (48); and tables whose dead entries name a NaR page.
+_BLOCKS = dict(seq_lens=[0, 1, 128, 129, 160], shuffled=True, D=64)
+_P8W20 = dict(_BLOCKS, page=8, W=20, G=1)
+_P16W12 = dict(_BLOCKS, seq_lens=[0, 1, 128, 129, 192], page=16, W=12, G=3)
+_P32W5 = dict(_BLOCKS, page=32, W=5, G=3)
+_NAR = dict(_P16W12, seq_lens=[1, 16, 128, 129, 150], nar_pages=True)
+_FMTS = {"float": None, "p16": P16_2, "p8": P8_2}
+
+
+def _cases(named, windowed=False):
+    """pytest params ([window,] pcfg, case) of (name, formats, case)."""
+    return [pytest.param(*([kw["window"]] if windowed else []), _FMTS[f], kw,
+                         id=f"{f}-{nm}")
+            for nm, fmts, kw in named for f in fmts]
+
+
+@pytest.mark.parametrize("pcfg,case", [
+    pytest.param(None, None, id="float"),
+    pytest.param(P16_2, None, id="p16"),
+    pytest.param(P8_2, None, id="p8"),
+    *_cases([
+        ("page8-W20-g1-d64", ("p16", "p8"), _P8W20),
+        ("page16-W12-g3-d64", ("float", "p16", "p8"), _P16W12),
+        ("page32-W5-g3-d64", ("float", "p16", "p8"), _P32W5),
+        ("page16-W12-g1-d128", ("p16", "p8"),
+         dict(_P16W12, seq_lens=[1, 127, 128, 129, 192], G=1, D=128)),
+        ("page16-W9-g3-d48", ("float", "p16"),
+         dict(_P16W12, seq_lens=[0, 17, 128, 129, 144], W=9, D=48)),
+        ("page16-W12-g2-d32-kv4", ("p16",), dict(_P16W12, G=2, D=32,
+                                                 n_kv=4)),
+        ("nar-pages", ("float", "p16", "p8"), _NAR),
+    ])])
+def test_paged_flash_decode_vs_materialized_dense_attention(pcfg, case):
+    """The Pallas paged-gather decode kernel (interpret mode) vs the
+    materialize_kv + dense flash-attention oracle at mixed lengths.  A
+    sequence of length 0 reads nothing and returns zeros."""
     from repro.kernels.flash_attention import paged_flash_decode
     from repro.kernels.ref import flash_attention_ref
 
-    rng = np.random.default_rng(3)
-    B, n_kv, G, D, page, W = 3, 2, 2, 16, 8, 4
-    H = n_kv * G
-    seq_lens = np.asarray([5, 17, 32], np.int32)
-    pt = np.asarray(_sequential_table(B, W))
-    kd = rng.normal(size=(1 + B * W, n_kv, page, D)).astype(np.float32)
-    vd = rng.normal(size=(1 + B * W, n_kv, page, D)).astype(np.float32)
-    q = jnp.asarray(rng.normal(size=(B, H, D)).astype(np.float32))
-    if pcfg is not None:
-        kp = f32_to_posit(jnp.asarray(kd), pcfg)
-        vp = f32_to_posit(jnp.asarray(vd), pcfg)
-    else:
-        kp, vp = jnp.asarray(kd), jnp.asarray(vd)
+    q, kp, vp, pt, _, seq_lens = _decode_case(
+        pcfg, **(case or dict(seq_lens=[5, 17, 32])))
+    B, H, D = q.shape
+    n_kv = kp.shape[1]
+    G = H // n_kv
+    W = pt.shape[1]
 
     out = paged_flash_decode(q, kp, vp, jnp.asarray(pt),
                              jnp.asarray(seq_lens), cfg_kv=pcfg,
                              interpret=True)
+    assert np.isfinite(np.asarray(out)).all()
     for i in range(B):
+        if seq_lens[i] == 0:
+            assert not np.asarray(out[i]).any()
+            continue
         # materialize this sequence's pages densely, run the ref oracle
         kk = np.concatenate([np.asarray(kp)[pt[i, j]] for j in range(W)],
                             axis=1)[:, :seq_lens[i]]
         vv = np.concatenate([np.asarray(vp)[pt[i, j]] for j in range(W)],
                             axis=1)[:, :seq_lens[i]]
-        qq = np.asarray(q[i]).reshape(n_kv, G, D)
-        for h in range(n_kv):
-            ref = flash_attention_ref(jnp.asarray(qq[h][None]),
-                                      jnp.asarray(kk[h][None]),
-                                      jnp.asarray(vv[h][None]),
-                                      cfg_kv=pcfg, causal=False)
-            got = np.asarray(out[i]).reshape(n_kv, G, D)[h]
-            np.testing.assert_allclose(got, np.asarray(ref[0]), rtol=2e-6,
-                                       atol=2e-6)
+        # one oracle call per sequence, batched over its KV heads (the
+        # group's query heads are that head's rows)
+        ref = flash_attention_ref(q[i].reshape(n_kv, G, D), jnp.asarray(kk),
+                                  jnp.asarray(vv), cfg_kv=pcfg, causal=False)
+        got = np.asarray(out[i]).reshape(n_kv, G, D)
+        np.testing.assert_allclose(got, np.asarray(ref), rtol=2e-6,
+                                   atol=2e-6)
 
 
-@pytest.mark.parametrize("pcfg", [None, P16_2], ids=["float", "p16"])
-@pytest.mark.parametrize("window", [4, 16])
-def test_paged_flash_decode_window_matches_gathered_reference(pcfg, window):
+@pytest.mark.parametrize("window,pcfg,case", [
+    *[pytest.param(w, _FMTS[f], None, id=f"{w}-{f}")
+      for w in (4, 16) for f in ("float", "p16")],
+    # windows smaller and larger than a block of ~128 tokens
+    *_cases([
+        ("w40-page16-W12-g3-d64", ("p16", "p8"), dict(_P16W12, window=40)),
+        ("w200-page16-W12-g3-d64", ("float", "p16", "p8"),
+         dict(_P16W12, window=200)),
+        ("w100-page8-W20-g1-d64", ("p16", "p8"), dict(_P8W20, window=100)),
+        ("w130-page32-W5-g3-d64", ("p16", "p8"), dict(_P32W5, window=130)),
+        ("w20-nar-pages", ("float", "p16", "p8"), dict(_NAR, window=20)),
+    ], windowed=True)])
+def test_paged_flash_decode_window_matches_gathered_reference(window, pcfg,
+                                                              case):
     """Windowed (local-attention) decode used to fall off the paged kernel
-    onto the dense gather_kv path; the kernel now masks the window itself
-    and must match the gathered blockwise reference at mixed lengths."""
-    from repro.core.convert import f32_to_posit
+    onto the dense gather_kv path; the kernel now masks the window itself,
+    skips the blocks wholly before it, and must match the gathered
+    blockwise reference at mixed lengths."""
     from repro.kernels.flash_attention import paged_flash_decode
     from repro.models.blocks import blockwise_attention
 
-    rng = np.random.default_rng(11)
-    B, n_kv, G, D, page, W = 3, 2, 2, 16, 8, 4
-    H = n_kv * G
-    seq_lens = jnp.asarray([3, 17, 32], jnp.int32)
-    pt = _sequential_table(B, W)
-    kd = jnp.asarray(rng.normal(size=(1 + B * W, n_kv, page, D)), jnp.float32)
-    vd = jnp.asarray(rng.normal(size=(1 + B * W, n_kv, page, D)), jnp.float32)
-    q = jnp.asarray(rng.normal(size=(B, H, D)), jnp.float32)
-    kp = f32_to_posit(kd, pcfg) if pcfg is not None else kd
-    vp = f32_to_posit(vd, pcfg) if pcfg is not None else vd
+    q, kp, vp, pt, clean, seq_lens = _decode_case(
+        pcfg, **(case or dict(seq_lens=[3, 17, 32], seed=11)))
+    n_kv = kp.shape[1]
+    seq_lens = jnp.asarray(seq_lens)
 
-    out = paged_flash_decode(q, kp, vp, pt, seq_lens, cfg_kv=pcfg,
-                             window=window, interpret=True)
+    out = paged_flash_decode(q, kp, vp, jnp.asarray(pt), seq_lens,
+                             cfg_kv=pcfg, window=window, interpret=True)
 
+    # the reference gathers every table entry, so it gets the clean table
+    pt = jnp.asarray(clean)
     if pcfg is not None:
         from repro.core.array import PositArray
         cache = {"k_pages": PositArray(kp, pcfg),
@@ -139,7 +215,11 @@ def test_paged_flash_decode_window_matches_gathered_reference(pcfg, window):
     ref = blockwise_attention(q[:, :, None, :], k, v, n_kv=n_kv, causal=True,
                               q_offset=seq_lens - 1, window=window,
                               kv_len=seq_lens)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref[:, :, 0, :]),
+    live = np.asarray(seq_lens) > 0
+    assert np.isfinite(np.asarray(out)).all()
+    assert not np.asarray(out)[~live].any()
+    np.testing.assert_allclose(np.asarray(out)[live],
+                               np.asarray(ref[:, :, 0, :])[live],
                                rtol=2e-6, atol=2e-6)
 
 

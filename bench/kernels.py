@@ -1,17 +1,11 @@
-"""Which device operations of the trace belong to which kernel class, and
-their roofline share.
+"""The roofline share of a kernel class.
 
-On the chip, a Pallas kernel's op in the trace is named after the
-function that makes its `pallas_call` (as read on one TPU v5e):
-`posit_gemm.N` (every posit GEMM: projections, MLP, unembedding),
-`paged_flash_decode.N` and `paged_flash_prefill.N`.
+Which device operations of the trace belong to which class is the
+configuration's family's (bench/families/<family>.py `KERNELS`), and
+reaches this reader as `ctx["kernels"]`, beside the work each class
+required of the window's steps (`ctx["step_work"]`).
 """
 from bench.trace import kernel_seconds
-
-KERNELS = {
-    "gemm": ("posit_gemm.",),
-    "attn": ("paged_flash_decode.", "paged_flash_prefill."),
-}
 
 
 def roofline(ctx, cls: str):
@@ -22,7 +16,7 @@ def roofline(ctx, cls: str):
     tr = ctx.get("trace")
     if not tr or not ctx.get("step_work"):
         return None
-    secs = kernel_seconds(tr["ops"], KERNELS[cls])
+    secs = kernel_seconds(tr["ops"], ctx["kernels"][cls])
     if not secs:
         return None
     pk = ctx["peaks"]

@@ -6,7 +6,6 @@ and the yardstick import none of this.
 from __future__ import annotations
 
 import dataclasses
-import functools
 import os
 import sys
 
@@ -22,15 +21,14 @@ from repro.kernels import ops as kops  # noqa: E402,F401 (re-exported)
 from repro.launch.compile_cache import enable_compile_cache  # noqa: E402,F401
 from repro.models.transformer import init_params  # noqa: E402
 from repro.quant.policy import PositPolicy  # noqa: E402
-from repro.quant.ptq import quantize_for_serving  # noqa: E402
+from repro.quant.ptq import quantize_for_serving  # noqa: E402,F401 (re-exported)
 from repro.serving.engine import PagedServingEngine  # noqa: E402
 
-from bench import weights as W  # noqa: E402
 
-
-def model_config(arch: str, m: dict):
+def model_config(arch: str, m: dict, fam):
     """The program's ModelConfig for config-file model block `m`, built
-    from the program's own registry and checked key by key against `m`."""
+    from the program's own registry and checked key by key against what
+    family `fam` says `m` states."""
     wn, wes = m["weight_posit"]
     kn, kes = m["kv_posit"]
     policy = PositPolicy(weights=PositConfig(wn, wes),
@@ -40,15 +38,7 @@ def model_config(arch: str, m: dict):
                 n_heads=m["n_heads"], n_kv=m["n_kv_heads"],
                 head_dim=m["head_dim"], d_ff=m["d_ff"], vocab=m["vocab"])
     cfg = dataclasses.replace(cfg, **over)
-    stated = {"act": m["act"], "tie_embeddings": m["tie_embeddings"],
-              "rope_theta": m["rope_theta"], "hd": m["head_dim"],
-              "norm": "rmsnorm", "block_pattern": ("attn",),
-              "qkv_bias": False, "embed_scale": False,
-              "moe": None}
-    got = {"act": cfg.act, "tie_embeddings": cfg.tie_embeddings,
-           "rope_theta": cfg.rope_theta, "hd": cfg.hd, "norm": cfg.norm,
-           "block_pattern": cfg.block_pattern, "qkv_bias": cfg.qkv_bias,
-           "embed_scale": cfg.embed_scale, "moe": cfg.moe}
+    stated, got = fam.stated(m), fam.got(cfg)
     bad = {k: (stated[k], got[k]) for k in stated if stated[k] != got[k]}
     if bad:
         raise SystemExit(f"bench: the program's {arch} config departs from "
@@ -56,10 +46,11 @@ def model_config(arch: str, m: dict):
     return cfg
 
 
-def check_layout(cfg, m: dict):
-    """The weight maker's layout must be the program's parameter tree."""
+def check_layout(cfg, m: dict, fam):
+    """Family `fam`'s weight maker must give the program's parameter
+    tree."""
     want = jax.eval_shape(lambda k: init_params(k, cfg), jax.random.PRNGKey(0))
-    got = jax.eval_shape(lambda k: W.nest(W.make_flat(k, m)),
+    got = jax.eval_shape(lambda k: fam.nest(fam.make_flat(k, m)),
                          jax.random.PRNGKey(0))
     sw = jax.tree_util.tree_structure(want)
     sg = jax.tree_util.tree_structure(got)
@@ -68,20 +59,6 @@ def check_layout(cfg, m: dict):
     if sw != sg or shapes_w != shapes_g:
         raise SystemExit(f"bench: weight layout differs from the program's "
                          f"parameter tree:\n{sw}\n{sg}")
-
-
-@functools.partial(jax.jit, static_argnums=(1, 2))
-def _serving_params(key, mkey, pcfg):
-    m = dict(mkey)
-    return quantize_for_serving(W.nest(W.make_flat(key, m)), pcfg)
-
-
-def serving_params(seed: int, m: dict, cfg):
-    """Random weights from the seed, quantized by the program's PTQ, in one
-    jitted call on the device; the f32 tree lives only inside it."""
-    from bench.reference import model_key
-    return _serving_params(W.seed_key(seed), model_key(m),
-                           cfg.policy.weights)
 
 
 def make_engine(params, cfg, eng: dict, seed: int):

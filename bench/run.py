@@ -12,7 +12,9 @@ reports.  Every metric is a reader of its own, bench/metrics/<name>.py.
 With --trace 0 the result line gives the cell's end-to-end metrics; with
 --trace 1 the window is traced by the JAX profiler and the line gives its
 per-layer metrics, the device's busy time and a breakdown.  Both decide
-`correct` by the comparison with the plain reference (bench/reference.py).
+`correct` by the comparison with the plain reference of the configuration's
+model family (bench/families/<family>.py; "dense" where the configuration
+names none).
 
 The run fails, and prints no result, off a TPU or on fewer chips than the
 cell asks for.  --smoke (rehearsal only) runs the configuration's and the
@@ -136,7 +138,8 @@ def main(argv=None) -> int:
 
 
 def finish(cell, args, res, devs, chips) -> int:
-    from bench import lastline, trace as TR, work
+    from bench import families, lastline, trace as TR, work
+    fam = families.load(cell["config"])
     traced = bool(args.trace)
     kind = devs[0].device_kind
     try:
@@ -147,7 +150,7 @@ def finish(cell, args, res, devs, chips) -> int:
         pk = work.peaks("TPU v5 lite")     # rehearsal arithmetic only
     ctx = {"setup_s": res["setup_s"], "window_s": res["window_s"],
            "reads": res["reads"], "peaks": pk, "trace": None,
-           "step_work": None}
+           "step_work": None, "kernels": fam.KERNELS}
     device = {"platform": devs[0].platform, "kind": kind, "count": chips,
               "memory_peak_bytes": int(res["peak_bytes"])}
     breakdown = None
@@ -162,8 +165,8 @@ def finish(cell, args, res, devs, chips) -> int:
         ctx["trace"] = red
         rows = res["reads"]["work_rows"]
         if rows is not None:
-            ctx["step_work"] = [work.step_work(res["reads"]["model"], r)
-                                for r in rows]
+            ctx["step_work"] = fam.step_work(res["reads"]["model"], rows,
+                                             res["reads"]["collected"])
         device["busy_s"] = red["busy_s"]
         device["window_s"] = red["window_s"]
         breakdown = TR.breakdown(red)

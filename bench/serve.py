@@ -7,8 +7,10 @@ step.  The backlog is then submitted, and the window opens once the first
 admission wave has prefilled; it drives `engine.step` under the
 configuration's matmul precision, on the host clock.
 
-After the window: the peak device memory is read, the program's state is
-freed, and the plain reference (bench/reference.py) is run over a sample,
+After the window: the peak device memory is read, the configuration's
+family (bench/families/) collects what its comparison takes from the live
+engine, the program's state is freed, and the family's plain reference
+(for the dense family, bench/reference.py) is run over a sample,
 drawn from the seed, of the requests that were served tokens, with the one
 served at the deepest position among them: each teacher-forced on its
 prompt and the tokens it was served, finished or not.  The number compared
@@ -120,8 +122,9 @@ def _warm_up(engine, m, eng, rng):
 def run(cell: dict, args, t_proc0: float, log) -> dict:
     import jax
 
-    from bench import program, reference
+    from bench import families, program
     conf, mix, m = cell["config"], cell["mix"], cell["model"]
+    fam = families.load(conf)
     eng, check = cell["engine"], cell["check"]
     if eng["prefill_chunk"] % eng["page_size"]:
         raise SystemExit("bench: prefill_chunk must be a multiple of "
@@ -133,11 +136,11 @@ def run(cell: dict, args, t_proc0: float, log) -> dict:
     compiles = _Compiles()
 
     marks = [time.perf_counter()]
-    cfg = program.model_config(conf["arch"], m)
-    program.check_layout(cfg, m)
+    cfg = program.model_config(conf["arch"], m, fam)
+    program.check_layout(cfg, m, fam)
     fb0 = program.fallback_count()
     with prec_ctx:
-        params = program.serving_params(args.seed, m, cfg)
+        params = fam.serving_params(args.seed, m, cfg)
         jax.block_until_ready(params)
         marks.append(time.perf_counter())
         engine = program.make_engine(params, cfg, eng, args.seed)
@@ -231,6 +234,7 @@ def run(cell: dict, args, t_proc0: float, log) -> dict:
     deepest = max((len(s) for s in seqs), default=0)
     spans_ms = [1e3 * (b - a) for a, b in step_spans]
     work_rows = book.steps[n_rows0:n_rows1] if book.sound else None
+    collected = fam.collect(engine, sample)
 
     # free the program's state before the reference runs
     del engine, params
@@ -238,8 +242,8 @@ def run(cell: dict, args, t_proc0: float, log) -> dict:
     program.free_device_memory()
 
     t_ref = time.perf_counter()
-    gaps, _, bad = reference.teacher_forced(m, args.seed, seqs, first,
-                                            m["context"])
+    gaps, _, bad = fam.teacher_forced(m, args.seed, seqs, first,
+                                      m["context"], collected=collected)
     ref_s = time.perf_counter() - t_ref
     per_req = [float(g.max()) if len(g) else float("nan") for g in gaps]
     worst = max((g for g in per_req if g == g), default=float("nan"))
@@ -263,6 +267,11 @@ def run(cell: dict, args, t_proc0: float, log) -> dict:
         "kernel_fallbacks": (fallbacks, 0, "max"),
         "compiles_in_window": (compiles.n, 0, "max"),
     }
+    extra = fam.extra_checks(m, check, collected)
+    if set(extra) & set(checks):
+        raise SystemExit(f"bench: the family's checks {sorted(extra)} "
+                         f"reuse a common name")
+    checks.update(extra)
     return {
         "setup_s": setup_s, "window_s": window_s, "checks": checks,
         "attempted": len(attempted_rids),
@@ -271,7 +280,8 @@ def run(cell: dict, args, t_proc0: float, log) -> dict:
         "failed": len(set(lost) | (set(mismatched) & set(attempted_rids))),
         "peak_bytes": peak, "log_dir": log_dir, "per_request_gaps": per_req,
         "reads": {"tokens_in_window": tok_in_win, "step_ms": spans_ms,
-                  "work_rows": work_rows, "model": m},
+                  "work_rows": work_rows, "model": m,
+                  "collected": collected},
     }
 
 

@@ -18,16 +18,17 @@ def main(out_dir: str) -> int:
     import json
     import numpy as np
     import jax
-    from bench import program
+    from bench import families, program
     from bench import trace as TR
     program.enable_compile_cache()
     with open(os.path.join(ROOT, "bench", "configs",
                            "smollm-360m.p16.json")) as f:
         conf = json.load(f)
     m, eng = conf["model"], conf["engine"]
-    cfg = program.model_config(conf["arch"], m)
+    fam = families.load(conf)
+    cfg = program.model_config(conf["arch"], m, fam)
     with jax.default_matmul_precision("highest"):
-        params = program.serving_params(1, m, cfg)
+        params = fam.serving_params(1, m, cfg)
         engine = program.make_engine(params, cfg, eng, 1)
         rng = np.random.default_rng(1)
         for _ in range(8):

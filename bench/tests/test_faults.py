@@ -48,23 +48,24 @@ def test_lower_precision_control_fails(seed):
     gap passes the configuration's rehearsal limit."""
     import json
     import os
-    from bench import reference
+    from bench import families
     from bench.tests.helpers import ROOT
     with open(os.path.join(ROOT, "bench", "configs",
                            "smollm-360m.p16.json")) as f:
         conf = json.load(f)
+    fam = families.load(conf)
     m = dict(conf["model"], **conf["smoke"]["model"])
     limit = conf["smoke"]["check"]["max_logit_gap"]
     rng = np.random.default_rng(seed % 2 ** 32)
     seqs = [rng.integers(0, m["vocab"], 200).astype(np.int32)
             for _ in range(4)]
     first = [20] * 4
-    gaps, bad = reference.control_gaps(m, seed, seqs, first, m["context"])
+    gaps, bad = fam.control_gaps(m, seed, seqs, first, m["context"])
     assert bad == 0
     worst = max(float(g.max()) for g in gaps)
     assert worst > limit, worst
     # and the reference at its own precision against itself reads 0
-    tops = reference.teacher_forced(m, seed, seqs, first, m["context"])[1]
-    again = reference.teacher_forced(m, seed, seqs, first, m["context"],
-                                     picks=tops)[0]
+    tops = fam.teacher_forced(m, seed, seqs, first, m["context"])[1]
+    again = fam.teacher_forced(m, seed, seqs, first, m["context"],
+                               picks=tops)[0]
     assert max(float(g.max()) for g in again) == 0.0

@@ -12,15 +12,16 @@ from bench.tests.helpers import ROOT
 
 
 def _engine(seed):
-    from bench import program
+    from bench import families, program
     with open(os.path.join(ROOT, "bench", "configs",
                            "smollm-360m.p16.json")) as f:
         conf = json.load(f)
     m = dict(conf["model"], **conf["smoke"]["model"])
     eng = dict(conf["engine"], **conf["smoke"]["engine"])
     eng.pop("num_pages")
-    cfg = program.model_config(conf["arch"], m)
-    engine = program.make_engine(program.serving_params(seed, m, cfg), cfg,
+    fam = families.load(conf)
+    cfg = program.model_config(conf["arch"], m, fam)
+    engine = program.make_engine(fam.serving_params(seed, m, cfg), cfg,
                                  eng, seed)
     taken = []
     inner = engine._step_fn

@@ -1,7 +1,10 @@
-"""bench/work.py against counts made by hand in this file."""
+"""bench/work.py and the dense family's step_work against counts made by hand
+in this file, and against totals pinned before the family seam."""
 import pytest
 
-from bench import work
+from bench import families, work
+
+DENSE = families.load({})
 
 SMOLLM = {"n_layers": 32, "d_model": 960, "n_heads": 15, "n_kv_heads": 5,
           "head_dim": 64, "d_ff": 2560, "vocab": 49152,
@@ -35,7 +38,7 @@ def test_flash_decode_and_prefill():
 
 def test_step_work_smollm_decode_step():
     # two sequences decoding with 99 and 499 tokens cached
-    w = work.step_work(SMOLLM, [(1, 99), (1, 499)])
+    w = DENSE.step_work(SMOLLM, [[(1, 99), (1, 499)]], None)[0]
     proj = 2 * 2 * (960 * 960 + 2 * 960 * 320 + 960 * 960)
     mlp = 2 * 2 * 3 * 960 * 2560
     unembed = 2 * 2 * 960 * 49152
@@ -48,6 +51,21 @@ def test_step_work_smollm_decode_step():
     acts = 32 * 2 * 4 * (2 * 1920 + 2 * 1280 + 2 * 3520 + 3520)
     unembed_bytes = 2 * 960 * 49152 + 2 * 4 * (960 + 49152)
     assert w["gemm"].bytes == weights + acts + unembed_bytes
+
+
+def test_dense_step_work_totals_pinned():
+    """The dense family's work for fixed rows (prefill chunks, decode at
+    several depths, an empty step), summed over the steps: the totals
+    bench/work.py gave before the family seam."""
+    steps = [[(128, 0), (128, 128), (37, 256)],
+             [(1, 99), (1, 1023), (1, 2047)], [], [(64, 0), (1, 500)]]
+    got = DENSE.step_work(SMOLLM, steps, None)
+    assert len(got) == len(steps)
+    tot = {k: (sum(w[k].ops for w in got), sum(w[k].bytes for w in got))
+           for k in ("gemm", "attn", "model")}
+    assert tot == {"gemm": (227876536320.0, 2955843584.0),
+                   "attn": (5999493120.0, 269516800.0),
+                   "model": (233876029440.0, 0.0)}
 
 
 def test_peaks_known_and_unknown():

@@ -70,6 +70,9 @@ def _rules():
         # column-parallel (input) projections
         (r"(wq|wk|wv|wg|w_up|w_gate|wr|w_x|w_gate_branch|w_input_gate|"
          r"w_rec_gate|wk)/w$", (FSDP, "model")),
+        # q/k norm scales follow their column-parallel projections (the
+        # norm's one psum over the full width: models/blocks.py _qk_norm)
+        (r"(q_norm|k_norm)/scale$", ("model",)),
         (r"w_lora_a$", (FSDP, None)),
         (r"w_lora_b$", (None, FSDP)),
         # everything small: replicate

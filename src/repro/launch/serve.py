@@ -189,6 +189,12 @@ def main():
         f"{ph}={stats[f'phase_ms_{ph}']:.1f}" for ph in PHASES)
         + f" | decode kv blocks run/grid={run}/{grid}"
         + (f" ({run / grid:.1%})" if grid else ""))
+    if cfg.moe is not None and stats["moe_pairs"]:
+        # rows per expert per layer-step, from the engine's routing record
+        hit, pairs = stats["moe_experts_hit"], stats["moe_pairs"]
+        print(f"[serve] expert load: pairs={pairs} experts_hit={hit} "
+              f"rows/expert mean={pairs / hit:.1f} "
+              f"max={stats['moe_rows_max']}")
     if results:
         first = results[min(results)]
         print(f"[serve] rid {min(results)}: {first[:12]}")

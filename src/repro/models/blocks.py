@@ -382,14 +382,37 @@ _fused_prefill.defvjp(_fused_prefill_fwd, _fused_prefill_bwd)
 # GQA attention block
 # --------------------------------------------------------------------------
 def init_attention(key, d_model: int, n_heads: int, n_kv: int, head_dim: int,
-                   qkv_bias: bool = False) -> Params:
+                   qkv_bias: bool = False, qk_norm: bool = False) -> Params:
     ks = jax.random.split(key, 4)
-    return {
+    p = {
         "wq": init_linear(ks[0], d_model, n_heads * head_dim, qkv_bias),
         "wk": init_linear(ks[1], d_model, n_kv * head_dim, qkv_bias),
         "wv": init_linear(ks[2], d_model, n_kv * head_dim, qkv_bias),
         "wo": init_linear(ks[3], n_heads * head_dim, d_model, False),
     }
+    if qk_norm:
+        p["q_norm"] = init_rmsnorm(n_heads * head_dim)
+        p["k_norm"] = init_rmsnorm(n_kv * head_dim)
+    return p
+
+
+def _qk_norm(q, k, p: Params, ctx, eps: float = 1e-6):
+    """RMSNorm of q [B, S, H*hd] and k [B, S, KV*hd] over their whole
+    projection widths (OLMoE's q/k norm).  Under a tensor_parallel context
+    this member holds a column slice of each projection (and of each
+    scale): one psum of the per-member sums of squares of both gives every
+    member the full widths' means."""
+    if ctx is None:
+        return rms_norm(q, p["q_norm"], eps), rms_norm(k, p["k_norm"], eps)
+    qf, kf = q.astype(jnp.float32), k.astype(jnp.float32)
+    ss = jax.lax.psum(jnp.stack([jnp.sum(qf * qf, axis=-1),
+                                 jnp.sum(kf * kf, axis=-1)]), ctx.axis)
+
+    def scaled(h, s, scale):
+        var = s[..., None] / (h.shape[-1] * ctx.size)
+        return h * jax.lax.rsqrt(var + eps) * scale
+    return (scaled(qf, ss[0], p["q_norm"]["scale"]).astype(q.dtype),
+            scaled(kf, ss[1], p["k_norm"]["scale"]).astype(k.dtype))
 
 
 def attention_block(x, p: Params, *, n_heads: int, n_kv: int, head_dim: int,
@@ -397,6 +420,10 @@ def attention_block(x, p: Params, *, n_heads: int, n_kv: int, head_dim: int,
                     window: int | None = None, rope_theta: float = 10000.0,
                     kv_cache=None, softcap: float | None = None):
     """Returns (out, new_kv_cache).  kv_cache: dict(k, v, length) or None.
+
+    With `q_norm`/`k_norm` in `p` (ModelConfig.qk_norm), q and k are
+    RMS-normalized over their whole projection widths before the head
+    reshape and RoPE, on every path (training, dense and paged caches).
 
     k/v cache tensors are [B, n_kv, S_max, head_dim]; PositArray pages when
     the cache was initialized with a posit format (decoded for compute here,
@@ -417,8 +444,15 @@ def attention_block(x, p: Params, *, n_heads: int, n_kv: int, head_dim: int,
         n_kv //= ctx.size
         x = block_grad_sync(x)
     B, S, _ = x.shape
-    q = linear(x, p["wq"], policy).reshape(B, S, n_heads, head_dim)
-    k = linear(x, p["wk"], policy).reshape(B, S, n_kv, head_dim)
+    if "q_norm" in p:
+        # q/k RMSNorm over the full projection widths, before RoPE
+        q, k = _qk_norm(linear(x, p["wq"], policy),
+                        linear(x, p["wk"], policy), p, ctx)
+        q = q.reshape(B, S, n_heads, head_dim)
+        k = k.reshape(B, S, n_kv, head_dim)
+    else:
+        q = linear(x, p["wq"], policy).reshape(B, S, n_heads, head_dim)
+        k = linear(x, p["wk"], policy).reshape(B, S, n_kv, head_dim)
     v = linear(x, p["wv"], policy).reshape(B, S, n_kv, head_dim)
 
     q = rope(q.transpose(0, 2, 1, 3), positions[:, None, :], rope_theta)

@@ -22,7 +22,8 @@ expert-parallel TP): top-k over the router softmax, per-dispatch-group
 arrival-order capacity positions, overflow drops, and combine weights
 renormalized over the *kept* experts only — a token whose sibling expert
 overflowed redistributes its mix instead of keeping a stale under-weighted
-sum.  The one-hot implementation survives as the CPU/interpret oracle and
+sum (qwen3-moe; OLMoE's `norm_topk=False` keeps the raw probabilities).
+The one-hot implementation survives as the CPU/interpret oracle and
 the benchmark baseline.  Training uses the grouped path too: the grouped
 custom_vjp supplies dX/dW Pallas kernels, and the shard_map train step
 (training/train_step.py) makes partitioning manual, so the old GSPMD
@@ -137,7 +138,7 @@ def _router_logits(xt, router, policy: PositPolicy):
 
 
 def _route(xt, p: Params, *, n_experts: int, top_k: int, cap: int,
-           policy: PositPolicy):
+           policy: PositPolicy, norm_topk: bool = True):
     """Shared routing math: (probs, gate_idx, onehot, pos, keep, comb_w).
 
     Identical for the grouped and one-hot paths (and replicated across
@@ -145,7 +146,9 @@ def _route(xt, p: Params, *, n_experts: int, top_k: int, cap: int,
     per-group arrival-order capacity position, and combine weights
     renormalized over the kept experts only — normalizing before the drop
     left overflow victims with a stale under-weighted mix (the pinned
-    forced-drop regression in tests/test_moe_grouped.py).
+    forced-drop regression in tests/test_moe_grouped.py).  norm_topk False
+    (OLMoE) skips the renormalization: the kept pairs weigh their softmax
+    probabilities as they are, dropped pairs 0.
     """
     G, gs, _ = xt.shape
     logits = _router_logits(xt, p["router"], policy)
@@ -166,8 +169,10 @@ def _route(xt, p: Params, *, n_experts: int, top_k: int, cap: int,
         pos = _arrival_positions(onehot)
         keep = pos < cap
 
-    kept = gate_vals * keep
-    comb_w = kept / jnp.maximum(kept.sum(axis=-1, keepdims=True), 1e-9)
+    comb_w = gate_vals * keep
+    if norm_topk:
+        comb_w = comb_w / jnp.maximum(comb_w.sum(axis=-1, keepdims=True),
+                                      1e-9)
     return probs, gate_idx, onehot, pos, keep, comb_w
 
 
@@ -295,8 +300,11 @@ def _dispatch_oneshot(xt, p: Params, *, n_experts: int, top_k: int, act: str,
 
 def moe_block(x, p: Params, *, n_experts: int, top_k: int, act: str,
               policy: PositPolicy, capacity_factor: float | None = 1.25,
-              group_size: int = 128):
-    """x [B, S, d] -> (out [B, S, d], aux_loss scalar).
+              group_size: int = 128, norm_topk: bool = True,
+              return_route: bool = False):
+    """x [B, S, d] -> (out [B, S, d], aux_loss scalar), and with
+    return_route the expert ids each token was routed to ([B, S, top_k]
+    int32, global ids also under expert-parallel TP).
 
     capacity_factor None disables overflow dropping entirely (cap covers
     every (token, k) pair).  Serving steps use this: capacity drops are a
@@ -339,7 +347,8 @@ def moe_block(x, p: Params, *, n_experts: int, top_k: int, act: str,
         cap = max(1, int(capacity_factor * gs * top_k / n_experts))
 
     probs, gate_idx, onehot, pos, keep, comb_w = _route(
-        xt, p, n_experts=n_experts, top_k=top_k, cap=cap, policy=policy)
+        xt, p, n_experts=n_experts, top_k=top_k, cap=cap, policy=policy,
+        norm_topk=norm_topk)
 
     # load-balancing aux loss (Switch): E * sum_e f_e * P_e — computed from
     # the global routing, so it is replicated under expert-parallel TP
@@ -376,4 +385,7 @@ def moe_block(x, p: Params, *, n_experts: int, top_k: int, act: str,
     # under expert-parallel TP each shard holds its experts' partial mix;
     # the block's one psum assembles the full output (identity otherwise)
     from repro.distributed.collectives import block_psum
-    return block_psum(out).reshape(B, S, d).astype(x.dtype), aux
+    out = block_psum(out).reshape(B, S, d).astype(x.dtype)
+    if return_route:
+        return out, aux, gate_idx.reshape(B, S, top_k)
+    return out, aux

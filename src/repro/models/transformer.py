@@ -34,6 +34,10 @@ class MoEConfig:
     # tokens on both MoE paths (models/moe.py — sort-based grouped GEMM on
     # the Pallas path, GShard one-hot as the jnp oracle)
     group_size: int = 128
+    # renormalize the kept top-k router probabilities to sum to one
+    # (qwen3-moe); False uses them as they are (OLMoE, norm_topk_prob
+    # false)
+    norm_topk: bool = True
 
 
 @dataclasses.dataclass(frozen=True)
@@ -48,6 +52,7 @@ class ModelConfig:
     head_dim: int = 0                 # 0 -> d_model // n_heads
     act: str = "swiglu"
     qkv_bias: bool = False
+    qk_norm: bool = False             # RMSNorm over the full q and k widths
     rope_theta: float = 10000.0
     norm: str = "rmsnorm"
     encoder_only: bool = False
@@ -81,6 +86,8 @@ class ModelConfig:
         per_layer = {}
         glu = 3 if self.act in ("geglu", "swiglu") else 2
         attn = d * self.hd * (self.n_heads + 2 * self.n_kv) + self.n_heads * self.hd * d
+        if self.qk_norm:
+            attn += self.hd * (self.n_heads + self.n_kv)
         if self.moe:
             mlp = d * self.moe.n_experts + self.moe.n_experts * glu * d * ff
         else:
@@ -117,7 +124,8 @@ def _init_block(key, kind: str, cfg: ModelConfig) -> Params:
     if kind in ("attn", "attn_local"):
         p = {"ln1": norm_init(cfg.d_model), "ln2": norm_init(cfg.d_model),
              "attn": B.init_attention(ks[0], cfg.d_model, cfg.n_heads,
-                                      cfg.n_kv, cfg.hd, cfg.qkv_bias)}
+                                      cfg.n_kv, cfg.hd, cfg.qkv_bias,
+                                      cfg.qk_norm)}
         if cfg.moe:
             p["moe"] = MOE.init_moe(ks[1], cfg.d_model, cfg.d_ff,
                                     cfg.moe.n_experts, cfg.act)
@@ -149,9 +157,23 @@ def _norm(x, p, cfg: ModelConfig):
     return shard_activation(h, "block_in")
 
 
+def _moe(h, p: Params, cfg: ModelConfig, capacity_factor, record: bool):
+    """The MoE block: (out, aux loss, [B, S, top_k] expert ids if `record`
+    else None)."""
+    res = MOE.moe_block(
+        h, p, n_experts=cfg.moe.n_experts, top_k=cfg.moe.top_k, act=cfg.act,
+        policy=cfg.policy, capacity_factor=capacity_factor,
+        group_size=cfg.moe.group_size, norm_topk=cfg.moe.norm_topk,
+        return_route=record)
+    return res if record else (*res, None)
+
+
 def _apply_block(x, p: Params, kind: str, cfg: ModelConfig, positions,
-                 cache, aux):
+                 cache, aux, record: bool = False):
+    """One layer: (x, new cache, aux, route), route the [B, S, top_k]
+    expert ids of an MoE layer when `record`, else None."""
     pol = cfg.policy
+    route = None
     if cfg.moe:
         # serving never drops: a per-group capacity would couple a token's
         # output to the other requests sharing its batch (and break
@@ -167,16 +189,13 @@ def _apply_block(x, p: Params, kind: str, cfg: ModelConfig, positions,
             rope_theta=cfg.rope_theta, kv_cache=cache)
         x = x + h.astype(x.dtype)
         if cfg.moe:
-            h, a = MOE.moe_block(_norm(x, p["ln2"], cfg), p["moe"],
-                                 n_experts=cfg.moe.n_experts,
-                                 top_k=cfg.moe.top_k, act=cfg.act, policy=pol,
-                                 capacity_factor=moe_cf,
-                                 group_size=cfg.moe.group_size)
+            h, a, route = _moe(_norm(x, p["ln2"], cfg), p["moe"], cfg,
+                               moe_cf, record)
             aux = aux + a
         else:
             h = B.mlp_block(_norm(x, p["ln2"], cfg), p["mlp"], act=cfg.act,
                             policy=pol)
-        return x + h.astype(x.dtype), new_cache, aux
+        return x + h.astype(x.dtype), new_cache, aux, route
     if kind == "rwkv6":
         if cache is None:                        # training / no-cache path
             h, new_t = RW.rwkv6_time_mix(_norm(x, p["ln1"], cfg), p["tmix"],
@@ -186,7 +205,7 @@ def _apply_block(x, p: Params, kind: str, cfg: ModelConfig, positions,
             h, new_c = RW.rwkv6_channel_mix(_norm(x, p["ln2"], cfg),
                                             p["cmix"], policy=pol,
                                             last_x=None)
-            return x + h.astype(x.dtype), (new_t, new_c), aux
+            return x + h.astype(x.dtype), (new_t, new_c), aux, None
         # serving: dense cache tuples or a posit state-pool dict — both run
         # the stateful chunk-invariant path (serving/backends.py)
         from repro.serving import backends as SB
@@ -215,7 +234,7 @@ def _apply_block(x, p: Params, kind: str, cfg: ModelConfig, positions,
                          "seq_lens": sl, "num_new": nn}
         else:
             new_cache = ((S_fin, t_last), c_last)
-        return x, new_cache, aux
+        return x, new_cache, aux, None
     if kind == "rglru":
         if cache is None:                        # training / no-cache path
             h, new_state = GR.rglru_block(_norm(x, p["ln1"], cfg), p["rec"],
@@ -241,16 +260,13 @@ def _apply_block(x, p: Params, kind: str, cfg: ModelConfig, positions,
                 new_state = (h_fin, conv_last)
         x = x + h.astype(x.dtype)
         if cfg.moe:
-            h, a = MOE.moe_block(_norm(x, p["ln2"], cfg), p["moe"],
-                                 n_experts=cfg.moe.n_experts,
-                                 top_k=cfg.moe.top_k, act=cfg.act, policy=pol,
-                                 capacity_factor=moe_cf,
-                                 group_size=cfg.moe.group_size)
+            h, a, route = _moe(_norm(x, p["ln2"], cfg), p["moe"], cfg,
+                               moe_cf, record)
             aux = aux + a
         else:
             h = B.mlp_block(_norm(x, p["ln2"], cfg), p["mlp"], act=cfg.act,
                             policy=pol)
-        return x + h.astype(x.dtype), new_state, aux
+        return x + h.astype(x.dtype), new_state, aux, route
     raise ValueError(kind)
 
 
@@ -428,7 +444,7 @@ def init_params(key, cfg: ModelConfig) -> Params:
 
 def forward(params: Params, cfg: ModelConfig, *, tokens=None,
             inputs_embeds=None, positions=None, caches=None,
-            return_hidden: bool = False):
+            return_hidden: bool = False, return_routes: bool = False):
     """Returns (logits [B,S,vocab], aux_loss, new_caches).
 
     tokens [B,S] int32 and/or inputs_embeds [B,Se,d] depending on
@@ -437,6 +453,10 @@ def forward(params: Params, cfg: ModelConfig, *, tokens=None,
     return_hidden: skip the unembedding and return the final normalized
     hidden states instead of logits (the chunked-loss training path —
     §Perf iteration A3 — computes the LM head per sequence chunk).
+    return_routes: also return, last, the expert ids every token was routed
+    to in each MoE layer, [MoE layers, B, S, top_k] int32 in layer order
+    (None for a model without MoE layers): the serving engine's routing
+    record.
     """
     pol = cfg.policy
     if cfg.input_mode == "embeddings":
@@ -472,16 +492,18 @@ def forward(params: Params, cfg: ModelConfig, *, tokens=None,
         x, aux = carry
         layer_params = inputs[0]
         layer_caches = inputs[1]
-        new_caches = []
+        new_caches, routes = [], []
         for pos in range(P):
             kind = cfg.block_pattern[pos]
             cache = layer_caches[pos] if serving else None
-            x, nc, aux = _apply_block(x, layer_params[pos], kind, cfg,
-                                      positions, cache, aux)
+            x, nc, aux, route = _apply_block(x, layer_params[pos], kind, cfg,
+                                             positions, cache, aux,
+                                             return_routes)
             new_caches.append(nc)
-        return (x, aux), tuple(new_caches)
+            routes.append(route)
+        return (x, aux), (tuple(new_caches), tuple(routes))
 
-    new_scanned = ()
+    new_scanned, scanned_routes = (), ()
     if reps:
         fn = jax.checkpoint(superblock,
                             policy=jax.checkpoint_policies.nothing_saveable) \
@@ -489,7 +511,7 @@ def forward(params: Params, cfg: ModelConfig, *, tokens=None,
         xs_caches = (scanned_caches if serving
                      else tuple(jnp.zeros((reps,)) for _ in range(P)))
         if cfg.scan_layers:
-            (x, aux), new_scanned = jax.lax.scan(
+            (x, aux), (new_scanned, scanned_routes) = jax.lax.scan(
                 fn, (x, aux), (params["scanned"], xs_caches))
         else:
             # unrolled python loop: identical math, per-layer ops visible to
@@ -502,28 +524,49 @@ def forward(params: Params, cfg: ModelConfig, *, tokens=None,
                 carry, nc = fn(carry, sl)
                 ys.append(nc)
             x, aux = carry
+            stack = lambda *z: jnp.stack(z)  # noqa: E731
             if serving:
                 new_scanned = jax.tree_util.tree_map(
-                    lambda *z: jnp.stack(z), *ys)
+                    stack, *[nc for nc, _ in ys])
+            if return_routes:
+                scanned_routes = jax.tree_util.tree_map(
+                    stack, *[r for _, r in ys])
 
-    new_rem = []
+    new_rem, rem_routes = [], []
     for i in range(cfg.pattern_rem):
         kind = cfg.block_pattern[i]
-        x, nc, aux = _apply_block(x, params["rem"][i], kind, cfg, positions,
-                                  rem_caches[i] if serving else None, aux)
+        x, nc, aux, route = _apply_block(
+            x, params["rem"][i], kind, cfg, positions,
+            rem_caches[i] if serving else None, aux, return_routes)
         new_rem.append(nc)
+        rem_routes.append(route)
 
     x = _norm(x, params["ln_f"], cfg)
     new_caches = ({"scanned": new_scanned, "rem": tuple(new_rem)}
                   if serving else None)
+    extra = ((_layer_routes(scanned_routes, rem_routes),)
+             if return_routes else ())
     if return_hidden:
-        return x, aux, new_caches
+        return (x, aux, new_caches) + extra
     if cfg.tie_embeddings:
         logits = B.unembed(x, params["embed"], pol)
     else:
         logits = B.linear(x, params["unembed"], pol).astype(jnp.float32)
     logits = shard_activation(logits, "logits")
-    return logits, aux, new_caches
+    return (logits, aux, new_caches) + extra
+
+
+def _layer_routes(scanned, rem):
+    """The MoE layers' expert ids in layer order (scanned layer
+    r * len(pattern) + position, then the rest): [layers, B, S, top_k],
+    or None when no layer routed."""
+    layers = []
+    st = [r for r in scanned if r is not None]     # each [reps, B, S, k]
+    if st:
+        st = jnp.stack(st, axis=1)                  # [reps, P, B, S, k]
+        layers.append(st.reshape((-1,) + st.shape[2:]))
+    layers += [r[None] for r in rem if r is not None]
+    return jnp.concatenate(layers) if layers else None
 
 
 def _cache_length(caches, cfg: ModelConfig):

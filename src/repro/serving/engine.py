@@ -222,12 +222,18 @@ def _step_body(cfg: ModelConfig, greedy: bool, p, tokens, pages, pt, sl, nn,
 
     with_logits: also return the [B, vocab] last-position logits (vocab-
     sharded under TP) as a fourth output — the engine's `record_logits`
-    mode, which compares logits against a reference path."""
+    mode, which compares logits against a reference path.
+
+    A MoE model's step also returns, last, the expert ids every row was
+    routed to, [layers, B, S, top_k] in the narrowest int that holds them
+    (the engine's routing record); a dense step returns nothing more."""
     from repro.distributed.collectives import tensor_parallel
 
     with tensor_parallel("model", tp_size, vocab_sharded, compress):
         caches = assemble_paged_caches(pages, pt, sl, nn)
-        logits, _, new_caches = forward(p, cfg, tokens=tokens, caches=caches)
+        logits, _, new_caches, *routes = forward(
+            p, cfg, tokens=tokens, caches=caches,
+            return_routes=cfg.moe is not None)
     # last *valid* position per slot (ragged prefill chunks)
     idx = jnp.clip(nn - 1, 0, tokens.shape[1] - 1)
     last = jnp.take_along_axis(logits, idx[:, None, None], axis=1)[:, 0]
@@ -244,9 +250,14 @@ def _step_body(cfg: ModelConfig, greedy: bool, p, tokens, pages, pt, sl, nn,
                              vocab_sharded=vocab_sharded)
     # a NaR'd row samples garbage (argmax over NaNs) — the host discards
     # the token for flagged slots and fails the request instead
+    out = (toks, nar, extract_paged_pages(new_caches))
     if with_logits:
-        return toks, nar, extract_paged_pages(new_caches), last
-    return toks, nar, extract_paged_pages(new_caches)
+        out += (last,)
+    if cfg.moe is not None:
+        # in the narrowest int that holds an expert id
+        out += (routes[0].astype(
+            jnp.int8 if cfg.moe.n_experts <= 128 else jnp.int16),)
+    return out
 
 
 @_step_cache(maxsize=64)
@@ -309,6 +320,9 @@ def _sharded_paged_step(cfg: ModelConfig, mesh, greedy: bool = True,
         out_specs = (P("data"), P("data"), paged_pool_pspecs(pages, mesh))
         if with_logits:
             out_specs += (P("data", "model" if vocab_sharded else None),)
+        if cfg.moe is not None:
+            # every model member routes alike (the router is replicated)
+            out_specs += (P(None, "data"),)
         return jax.shard_map(
             body, mesh=mesh,
             in_specs=(serving_param_pspecs(p, mesh), data_rows,
@@ -526,7 +540,12 @@ class PagedServingEngine:
     always on: with no profiler attached each costs one small object.  The
     same phases keep host-time totals, stats()' `phase_ms_<name>`, and
     `step_rows` records, per step that ran the device, its kind and the
-    (new tokens, tokens cached before) of every slot it computed.
+    (new tokens, tokens cached before) of every slot it computed.  A MoE
+    model's steps also keep the routing record `step_routes` (bounded like
+    `step_rows`): per step, the rids it computed for, their positions and
+    the expert ids each of their rows was routed to in every layer, fetched
+    with the sampled tokens; `routing(rid)` reads a request's back, and
+    stats()' `moe_pairs`, `moe_experts_hit` and `moe_rows_max` count it.
     """
 
     def __init__(self, params, cfg: ModelConfig, *, max_seqs: int = 8,
@@ -1188,7 +1207,9 @@ class PagedServingEngine:
                             "injected_nar_poisons",
                             "injected_page_poisons",
                             "decode_kv_blocks_run",
-                            "decode_kv_blocks_grid")}
+                            "decode_kv_blocks_grid",
+                            "moe_pairs", "moe_experts_hit",
+                            "moe_rows_max")}
         d.update(self.counters)
         d["gather_fallbacks"] = (sum(GATHER_FALLBACKS.values())
                                  - self._gather_base)
@@ -1218,6 +1239,10 @@ class PagedServingEngine:
         # (kind, ((new tokens, tokens cached before) per slot computed))
         self._step_lat_s: collections.deque = collections.deque(maxlen=4096)
         self.step_rows: collections.deque = collections.deque(maxlen=4096)
+        # MoE: per step that ran the device, (rids, positions before, new
+        # tokens, expert ids [layers, rids, tokens, top_k])
+        self.step_routes: collections.deque = collections.deque(
+            maxlen=4096)
         self._phase_s = dict.fromkeys(PHASES, 0.0)
         self._gather_base = sum(GATHER_FALLBACKS.values())
         self._moe_base = sum(DENSE_MOE_FALLBACKS.values())
@@ -1320,9 +1345,50 @@ class PagedServingEngine:
             del tok_in, inputs       # their release is this phase's work
         with self._phase("fetch"):
             toks, bad, last = jax.device_get((toks, bad, last))
+        if self.cfg.moe is not None:
+            *last, self._last_routes = last
         if last:
             self._last_logits = np.asarray(last[0])
         return np.asarray(toks), np.asarray(bad)
+
+    def _record_routes(self, part, num_new):
+        """Keep the step's routing (self._last_routes, [layers, max_seqs,
+        width, top_k]) for the slots in `part` (seq_lens still as before
+        the step), and count it: (token, expert) pairs, the experts that
+        received rows and the busiest expert's rows, per layer."""
+        ids = self._last_routes[:, part]
+        nums = num_new[part]
+        self.step_routes.append((
+            np.array([self.slots[i].req.rid for i in part]),
+            self.seq_lens[part], nums, ids))
+        L, _, width, k = ids.shape
+        E = self.cfg.moe.n_experts
+        rows = ids[:, np.arange(width)[None, :] < nums[:, None]]
+        load = np.bincount((rows.reshape(L, -1).astype(np.int64)
+                            + E * np.arange(L)[:, None]).ravel(),
+                           minlength=L * E)
+        self.counters["moe_pairs"] += rows.size
+        self.counters["moe_experts_hit"] += int(np.count_nonzero(load))
+        self.counters["moe_rows_max"] = max(self.counters["moe_rows_max"],
+                                            int(load.max()))
+
+    def routing(self, rid: int) -> np.ndarray:
+        """[positions, layers, top_k] expert ids request `rid` was routed to
+        at each of its positions the routing record holds (-1 where it
+        holds none: a position whose step left the record, or one a prefix
+        hit skipped); a position computed again, after a preemption, reads
+        its latest routing."""
+        got = [(st[j], n[j], ids[:, j])
+               for rids, st, n, ids in self.step_routes
+               for j in np.flatnonzero(rids == rid)]
+        if not got:
+            return np.zeros((0, 0, 0), np.int8)
+        L, _, k = got[0][2].shape
+        out = np.full((max(s + n for s, n, _ in got), L, k), -1,
+                      got[0][2].dtype)
+        for s, n, ids in got:
+            out[s:s + n] = ids[:, :n].transpose(1, 0, 2)
+        return out
 
     def _count_decode_blocks(self):
         """stats()' decode_kv_blocks_run: the blocks of pages that this
@@ -1460,6 +1526,8 @@ class PagedServingEngine:
             # with its Python tracer on would record and the step would pay
             self.step_rows.append((kind, tuple(zip(
                 num_new[part].tolist(), self.seq_lens[part].tolist()))))
+            if self.cfg.moe is not None:
+                self._record_routes(part, num_new)
             self.seq_lens += num_new
             if kind == "decode" and self._decode_windows:
                 self._count_decode_blocks()

@@ -3,7 +3,7 @@
 Nothing runs: each kernel is lowered and compiled by the TPU compiler for
 a chip that is described, not attached, at smollm-360m widths (d_model
 960, 15 heads over 5 KV heads of 64, d_ff 2560, vocab 49152, 16-token
-pages).  This catches what interpret mode cannot: Mosaic's tiling rules,
+pages), and the grouped expert GEMM at olmoe-1b-7b's.  This catches what interpret mode cannot: Mosaic's tiling rules,
 its supported casts and reshapes, and the scoped-VMEM budget.  The
 topology is described inside a fixture, never at import, so every
 pytest-xdist worker collects the same tests and only the worker that runs
@@ -95,6 +95,14 @@ CASES = [
     ("posit_grouped_gemm-p16", gg.posit_grouped_gemm,
      [((2048, 1024), F32), ((64, 1024, 1024), I16), ((65,), I32)],
      {"cfg_b": P16_2}),
+    # the olmoe-1b-7b.p16.offline cell (d_model 2048, 64 experts of 1024):
+    # decode, 64 sequences x top-8 = 512 rows; prefill, 64 x 128 x 8
+    *[(f"posit_grouped_gemm-p16-olmoe-{step}-{k}to{n}",
+       gg.posit_grouped_gemm,
+       [((rows, k), F32), ((64, k, n), I16), ((65,), I32)],
+       {"cfg_b": P16_2})
+      for step, rows in (("decode", 512), ("prefill", 65536))
+      for k, n in ((2048, 1024), (1024, 2048))],
     # 13 tokens: two 8-row time tiles, the second padded and masked
     ("wkv_scan-p16", rs.wkv_scan_pallas,
      [((8, 40, 13, 64), F32)] * 4
@@ -128,6 +136,9 @@ OP_NAMES = {
     "encode_block-p16": "encode_block",
     "elementwise_add-p16": "elementwise",
     "posit_grouped_gemm-p16": "posit_grouped_gemm",
+    **{f"posit_grouped_gemm-p16-olmoe-{step}-{k}to{n}": "posit_grouped_gemm"
+       for step in ("decode", "prefill") for k, n in ((2048, 1024),
+                                                      (1024, 2048))},
     "wkv_scan-p16": "wkv_scan_pallas",
     "rglru_scan-p16": "rglru_scan_pallas",
 }

@@ -132,3 +132,73 @@ def test_ste_gradient_passthrough():
     g = jax.grad(lambda x: (posit_cast_ste(x, P16_2) ** 2).sum())(w)
     # STE: d/dw (q(w)^2) = 2*q(w) (gradient flows through cast unchanged)
     np.testing.assert_allclose(g, 2 * posit_cast_ste(w, P16_2), rtol=1e-6)
+
+
+def _qk_norm_params(cfg, seed):
+    """init_params with the q/k norm scales drawn away from 1, so that a
+    missing scale shows."""
+    params = init_params(jax.random.PRNGKey(seed), cfg)
+    attn = params["scanned"][0]["attn"]
+    for i, name in enumerate(("q_norm", "k_norm")):
+        s = attn[name]["scale"]
+        attn[name]["scale"] = 1.0 + 0.3 * jax.random.normal(
+            jax.random.PRNGKey(seed + 10 + i), s.shape)
+    return params
+
+
+def test_qk_norm_matches_plain_formula():
+    """attention_block with qk_norm: q and k RMS-normalized over their whole
+    projection widths (not per head), scaled, then rotated, as written out
+    here in plain jnp."""
+    from repro.models import blocks as B
+    d, H, KV, hd, S = 32, 4, 2, 8, 6
+    p = B.init_attention(jax.random.PRNGKey(0), d, H, KV, hd, qk_norm=True)
+    p["q_norm"]["scale"] = 1 + 0.3 * jax.random.normal(
+        jax.random.PRNGKey(1), (H * hd,))
+    p["k_norm"]["scale"] = 1 + 0.3 * jax.random.normal(
+        jax.random.PRNGKey(2), (KV * hd,))
+    x = jax.random.normal(jax.random.PRNGKey(3), (1, S, d))
+    pos = jnp.arange(S)[None]
+    got, _ = B.attention_block(x, p, n_heads=H, n_kv=KV, head_dim=hd,
+                               positions=pos, policy=PositPolicy())
+
+    def plain(per_head):
+        q, k, v = (x[0] @ p[n]["w"] for n in ("wq", "wk", "wv"))
+
+        def rms(t, scale, width):
+            t = t.reshape(S, -1, width)
+            t = t / jnp.sqrt(jnp.mean(t * t, -1, keepdims=True) + 1e-6)
+            return t.reshape(S, -1) * scale
+        w = hd if per_head else None
+        q = rms(q, p["q_norm"]["scale"], w or H * hd).reshape(S, H, hd)
+        k = rms(k, p["k_norm"]["scale"], w or KV * hd).reshape(S, KV, hd)
+        q = B.rope(q.transpose(1, 0, 2), pos)
+        k = jnp.repeat(B.rope(k.transpose(1, 0, 2), pos), H // KV, 0)
+        v = jnp.repeat(v.reshape(S, KV, hd).transpose(1, 0, 2), H // KV, 0)
+        s = jnp.einsum("hqd,hkd->hqk", q, k) * hd ** -0.5
+        s = jnp.where(jnp.tril(jnp.ones((S, S), bool)), s, -jnp.inf)
+        o = jnp.einsum("hqk,hkd->qhd", jax.nn.softmax(s, -1), v)
+        return (o.reshape(S, H * hd) @ p["wo"]["w"])[None]
+
+    np.testing.assert_allclose(np.asarray(got), np.asarray(plain(False)),
+                               rtol=1e-5, atol=1e-5)
+    # a per-head norm is another model: the check can tell them apart
+    assert float(jnp.abs(got - plain(True)).max()) > 1e-3
+
+
+def test_qk_norm_serving_matches_full_forward():
+    """With qk_norm, prefill then decode through the dense cache gives the
+    full forward's logits."""
+    cfg = ModelConfig("eq-qk", n_layers=2, d_model=48, n_heads=4, n_kv=2,
+                      d_ff=96, vocab=64, qk_norm=True)
+    params = _qk_norm_params(cfg, 20)
+    toks = jax.random.randint(jax.random.PRNGKey(3), (2, 20), 0, 64)
+    full, _, _ = forward(params, cfg, tokens=toks)
+    caches = init_caches(cfg, 2, 24)
+    lg, _, caches = forward(params, cfg, tokens=toks[:, :12], caches=caches)
+    errs = [float(jnp.abs(lg[:, -1] - full[:, 11]).max())]
+    for i in range(12, 20):
+        lg, _, caches = forward(params, cfg, tokens=toks[:, i:i + 1],
+                                caches=caches)
+        errs.append(float(jnp.abs(lg[:, 0] - full[:, i]).max()))
+    assert max(errs) < 1e-4

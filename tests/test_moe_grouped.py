@@ -91,6 +91,28 @@ def test_grouped_gemm_tile_straddling_groups():
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref), **TOL)
 
 
+@pytest.mark.parametrize("transpose_b", [False, True], ids=["fwd", "dx"])
+@pytest.mark.parametrize("precision", ["highest", "default"])
+def test_grouped_gemm_dots_take_the_matmul_precision(precision, transpose_b):
+    """The kernel's dots carry no precision of their own: traced under
+    jax.default_matmul_precision they take the context's, which Mosaic
+    lowers to an f32 contraction for "highest" (the precision a posit16
+    serving step states) and to its bf16 default otherwise."""
+    import re
+    E, K, N = 4, 256, 128
+    x = jax.ShapeDtypeStruct((64, N if transpose_b else K), jnp.float32)
+    w = jax.ShapeDtypeStruct((E, K, N), jnp.int16)
+    off = jax.ShapeDtypeStruct((E + 1,), jnp.int32)
+    with jax.default_matmul_precision(precision):
+        text = str(jax.make_jaxpr(
+            lambda a, b, c: posit_grouped_gemm(
+                a, b, c, cfg_b=P16_2, transpose_b=transpose_b,
+                interpret=True))(x, w, off))
+    got = re.findall(r"precision=\(Precision\.(\w+), Precision\.(\w+)\)",
+                     text)
+    assert got and set(got) == {(precision.upper(),) * 2}, got
+
+
 def test_grouped_matmul_dispatch_requires_cfg_for_raw_ints():
     x = jnp.zeros((4, 8), jnp.float32)
     w = jnp.zeros((2, 8, 8), jnp.int16)
@@ -154,9 +176,11 @@ def test_moe_grouped_no_capacity_matches_oracle(monkeypatch):
 # --------------------------------------------------------------------------
 # forced drops: combine weights renormalize over the *kept* experts
 # --------------------------------------------------------------------------
-def _numpy_moe_oracle(x, p, *, n_experts, top_k, act, cap, group_size):
+def _numpy_moe_oracle(x, p, *, n_experts, top_k, act, cap, group_size,
+                      norm_topk=True):
     """Independent numpy reimplementation of routing + dispatch with the
-    kept-only renormalization — the pinned semantics both paths must hit."""
+    kept-only renormalization — the pinned semantics both paths must hit.
+    norm_topk False: the kept pairs weigh their raw probabilities."""
     assert act == "gelu"
     B, S, d = x.shape
     T = B * S
@@ -178,7 +202,8 @@ def _numpy_moe_oracle(x, p, *, n_experts, top_k, act, cap, group_size):
                 keep[t, j] = True
             fill[(g, e)] = c + 1
     kept = gate * keep
-    w = kept / np.maximum(kept.sum(-1, keepdims=True), 1e-9)
+    w = kept / np.maximum(kept.sum(-1, keepdims=True), 1e-9) if norm_topk \
+        else kept
     wu = np.asarray(p["w_up"], np.float64)
     wd = np.asarray(p["w_down"], np.float64)
 
@@ -216,6 +241,37 @@ def test_forced_drop_renormalizes_over_kept_experts(monkeypatch, grouped):
     got, _ = MOE.moe_block(x, p, n_experts=E, top_k=k, act="gelu",
                            policy=NONE, capacity_factor=0.25, group_size=8)
     np.testing.assert_allclose(np.asarray(got), want, rtol=1e-3, atol=1e-4)
+
+
+@pytest.mark.parametrize("grouped", [False, True], ids=["oneshot", "grouped"])
+def test_norm_topk_false_keeps_raw_probabilities(monkeypatch, grouped):
+    """norm_topk=False (OLMoE): the combine weights are the kept top-k
+    softmax probabilities as they are, dropped pairs 0, on both dispatch
+    paths; with capacity drops forced as in the test above."""
+    E, k, d, ff, B, S = 4, 2, 16, 24, 1, 8
+    p = MOE.init_moe(jax.random.PRNGKey(4), d, ff, E, "gelu")
+    x = jax.random.normal(jax.random.PRNGKey(5), (B, S, d))
+    xt = x.reshape(1, S, d)
+    probs, idx, _, _, keep, w = MOE._route(
+        xt, p, n_experts=E, top_k=k, cap=1, policy=NONE, norm_topk=False)
+    raw = jnp.take_along_axis(probs, idx, axis=-1)
+    np.testing.assert_array_equal(np.asarray(w),
+                                  np.asarray(jnp.where(keep, raw, 0.0)))
+    assert not bool(keep.all()), "no pair dropped; test vacuous"
+    want, _ = _numpy_moe_oracle(x, p, n_experts=E, top_k=k, act="gelu",
+                                cap=1, group_size=8, norm_topk=False)
+    if grouped:
+        _pallas_interpret_env(monkeypatch)
+        monkeypatch.setattr(MOE, "FORCE_GROUPED", True)
+    got, _ = MOE.moe_block(x, p, n_experts=E, top_k=k, act="gelu",
+                           policy=NONE, capacity_factor=0.25, group_size=8,
+                           norm_topk=False)
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-3, atol=1e-4)
+    # and renormalizing is another answer
+    renorm, _ = MOE.moe_block(x, p, n_experts=E, top_k=k, act="gelu",
+                              policy=NONE, capacity_factor=0.25,
+                              group_size=8)
+    assert float(jnp.abs(renorm - got).max()) > 1e-3
 
 
 # --------------------------------------------------------------------------
@@ -312,6 +368,82 @@ def test_engine_drain_grouped_no_dense_decode_and_bit_parity(monkeypatch):
     assert dict(paged_kv.GATHER_FALLBACKS) == before_g
     for r in ref:
         assert np.array_equal(ref[r], res[r]), (r, ref[r], res[r])
+
+
+def _routes_by_layer(params, cfg, seq):
+    """The expert ids `_route` gives each position of `seq` in every layer
+    ([positions, layers, top_k]): the forward pass written out layer by
+    layer from the model's blocks, without caches, capacity or engine."""
+    from repro.models import blocks as B
+    from repro.models.transformer import _norm
+    x = B.embed(jnp.asarray(seq)[None], params["embed"], cfg.policy)
+    pos = jnp.arange(len(seq))[None]
+    out = []
+    for layer in range(cfg.n_layers):
+        p = jax.tree_util.tree_map(lambda t: t[layer], params["scanned"][0])
+        h, _ = B.attention_block(
+            _norm(x, p["ln1"], cfg), p["attn"], n_heads=cfg.n_heads,
+            n_kv=cfg.n_kv, head_dim=cfg.hd, positions=pos, policy=cfg.policy)
+        x = x + h
+        hn = _norm(x, p["ln2"], cfg)
+        _, idx, *_ = MOE._route(hn, p["moe"], n_experts=cfg.moe.n_experts,
+                                top_k=cfg.moe.top_k, cap=len(seq),
+                                policy=cfg.policy,
+                                norm_topk=cfg.moe.norm_topk)
+        out.append(np.asarray(idx[0]))
+        h, _ = MOE.moe_block(hn, p["moe"], n_experts=cfg.moe.n_experts,
+                             top_k=cfg.moe.top_k, act=cfg.act,
+                             policy=cfg.policy, capacity_factor=None,
+                             group_size=len(seq),
+                             norm_topk=cfg.moe.norm_topk)
+        x = x + h
+    return np.stack(out, axis=1)
+
+
+def test_routing_record_matches_route():
+    """The engine's routing record, read back per request, holds the expert
+    ids `_route` gives every position the request computed (its prompt's
+    prefill chunks and its decode steps), and its counters add up."""
+    from repro.serving import engine as E
+
+    cfg = _olmoe_cfg("olmoe-route-record")
+    params = init_params(jax.random.PRNGKey(11), cfg)
+    rng = np.random.default_rng(12)
+    reqs = [(rng.integers(0, cfg.vocab, int(n)).astype(np.int32), 5)
+            for n in (3, 11, 7, 14, 5)]
+    eng = E.PagedServingEngine(params, cfg, max_seqs=4, page_size=4,
+                               table_width=8, prefill_chunk=8)
+    res = eng.run([(p.copy(), n) for p, n in reqs])
+    k, L = cfg.moe.top_k, cfg.n_layers
+    computed = 0
+    for rid, (prompt, _) in enumerate(reqs):
+        seq = np.concatenate([prompt, res[rid][:-1]])
+        rec = eng.routing(rid)
+        assert rec.dtype == np.int8 and rec.shape == (len(seq), L, k)
+        want = _routes_by_layer(params, cfg, seq)
+        assert np.array_equal(np.sort(rec, -1), np.sort(want, -1)), rid
+        computed += len(seq)
+    st = eng.stats()
+    assert st["moe_pairs"] == computed * L * k
+    assert 0 < st["moe_experts_hit"] <= len(eng.step_routes) * L * \
+        cfg.moe.n_experts
+    assert 1 <= st["moe_rows_max"] <= 4 * 8
+    eng.reset_stats()
+    assert eng.stats()["moe_pairs"] == 0 and not eng.step_routes
+    assert eng.routing(0).size == 0
+
+
+def test_dense_step_returns_no_routing():
+    """Only a MoE model's step returns routing: the dense step's outputs
+    are unchanged."""
+    from repro.serving import engine as E
+    cfg = ModelConfig(name="dense-noroute", n_layers=1, d_model=16,
+                      n_heads=2, n_kv=2, d_ff=32, vocab=32)
+    eng = E.PagedServingEngine(init_params(jax.random.PRNGKey(0), cfg), cfg,
+                               max_seqs=2, page_size=4, table_width=4,
+                               prefill_chunk=4)
+    eng.run([(np.arange(5, dtype=np.int32), 3)])
+    assert not eng.step_routes and eng.stats()["moe_pairs"] == 0
 
 
 def test_serving_moe_output_independent_of_batch_composition():

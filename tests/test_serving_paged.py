@@ -417,3 +417,34 @@ def test_paged_step_cache_keys_on_kernel_dispatch(monkeypatch):
     assert E.PagedServingEngine(params, cfg, **kw)._step_fn \
         is not ref._step_fn
     assert E._dense_steps(cfg) is not ref_dense
+
+
+@pytest.mark.parametrize("pcfg", [None, P16_2], ids=["float", "p16"])
+def test_qk_norm_paged_prefill_then_decode_matches_full_forward(pcfg):
+    """With qk_norm, a paged prefill then paged decode steps give the full
+    forward's logits (up to the KV format's rounding)."""
+    cfg = _cfg(pcfg, qk_norm=True)
+    params = init_params(jax.random.PRNGKey(5), cfg)
+    for name in ("q_norm", "k_norm"):
+        s = params["scanned"][0]["attn"][name]["scale"]
+        params["scanned"][0]["attn"][name]["scale"] = 1.0 + 0.3 * \
+            jax.random.normal(jax.random.PRNGKey(len(name)), s.shape)
+    B, S, T, page, W = 2, 6, 10, 4, 8
+    toks = jax.random.randint(jax.random.PRNGKey(6), (B, T), 0, cfg.vocab)
+    full, _, _ = forward(params, cfg, tokens=toks)
+    pages = init_paged_pages(cfg, num_pages=1 + B * W, page_size=page)
+    pt = _sequential_table(B, W)
+    caches = assemble_paged_caches(pages, pt, jnp.zeros((B,), jnp.int32),
+                                   jnp.full((B,), S, jnp.int32))
+    lg, _, caches = forward(params, cfg, tokens=toks[:, :S], caches=caches)
+    errs = [float(jnp.abs(lg[:, -1] - full[:, S - 1]).max())]
+    for i in range(S, T):
+        caches = assemble_paged_caches(
+            extract_paged_pages(caches), pt, jnp.full((B,), i, jnp.int32),
+            jnp.ones((B,), jnp.int32))
+        lg, _, caches = forward(params, cfg, tokens=toks[:, i:i + 1],
+                                caches=caches)
+        errs.append(float(jnp.abs(lg[:, 0] - full[:, i]).max()))
+    # the full forward keeps K/V in f32; posit16 KV pages round them to
+    # ~2^-12 relative, which moves these logits by up to ~1.2e-3
+    assert max(errs) < (1e-4 if pcfg is None else 5e-3), errs

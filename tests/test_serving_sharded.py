@@ -253,3 +253,68 @@ def test_sharded_vs_single_device_bit_exact_8dev():
                          capture_output=True, text=True, timeout=900)
     assert out.returncode == 0, out.stderr[-4000:]
     assert "SHARDED-OK" in out.stdout
+
+
+# ---- q/k norm and the routing record under TP: forced 4-device host ------
+_MOE_SUBPROCESS = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import numpy as np, jax, jax.numpy as jnp
+    from repro.core.types import P16_2
+    from repro.models.transformer import ModelConfig, MoEConfig, init_params
+    from repro.quant.policy import PositPolicy
+    from repro.serving import engine as E
+    from repro.launch.mesh import make_serving_mesh
+
+    # full-width q/k norm (one psum of both shards' sums of squares) and
+    # un-renormalized top-k, as OLMoE serves them
+    cfg = ModelConfig(name="tst-sh-moe", n_layers=2, d_model=32, n_heads=4,
+                      n_kv=2, d_ff=16, vocab=50, qk_norm=True,
+                      tie_embeddings=False,
+                      moe=MoEConfig(n_experts=8, top_k=2, norm_topk=False),
+                      policy=PositPolicy(kv_cache=P16_2))
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    # scales away from one: a shard that normed or scaled with the wrong
+    # columns would show
+    key = jax.random.PRNGKey(1)
+    attn = params["scanned"][0]["attn"]
+    for i, name in enumerate(("q_norm", "k_norm")):
+        s = attn[name]["scale"]
+        attn[name]["scale"] = s + 0.5 * jax.random.normal(
+            jax.random.fold_in(key, i), s.shape)
+    rng = np.random.default_rng(11)
+    reqs = [(rng.integers(0, cfg.vocab, int(rng.integers(3, 14))
+                          ).astype(np.int32), 6) for _ in range(6)]
+
+    def drain(mesh):
+        eng = E.PagedServingEngine(params, cfg, max_seqs=4, page_size=4,
+                                   table_width=8, prefill_chunk=8, mesh=mesh,
+                                   record_logits=True)
+        return eng, eng.run([(p.copy(), n) for p, n in reqs])
+
+    ref, res_ref = drain(None)
+    eng, res = drain(make_serving_mesh(2, 2))
+    assert any(k[0] == "sharded_paged_step" for k in E.STEP_TRACES)
+    assert sorted(res) == sorted(res_ref)
+    for r in res_ref:
+        assert np.array_equal(res[r], res_ref[r]), r
+        np.testing.assert_allclose(np.stack(eng.token_logits[r]),
+                                   np.stack(ref.token_logits[r]),
+                                   rtol=1e-5, atol=1e-5)
+        got, want = eng.routing(r), ref.routing(r)
+        assert want.shape[1:] == (cfg.n_layers, 2) and (want >= 0).all()
+        assert np.array_equal(got, want), r
+    assert eng.stats()["moe_pairs"] == ref.stats()["moe_pairs"] > 0
+    print("SHARDED-MOE-OK")
+""")
+
+
+def test_sharded_moe_qk_norm_matches_unsharded_4dev():
+    env = dict(os.environ,
+               PYTHONPATH=os.path.join(os.path.dirname(__file__), "..",
+                                       "src"))
+    env.pop("XLA_FLAGS", None)
+    out = subprocess.run([sys.executable, "-c", _MOE_SUBPROCESS], env=env,
+                         capture_output=True, text=True, timeout=900)
+    assert out.returncode == 0, out.stderr[-4000:]
+    assert "SHARDED-MOE-OK" in out.stdout
